@@ -37,6 +37,7 @@ import time
 import jax
 import numpy as np
 
+from benchmarks.paged_decode import require_tpu
 from repro.configs import get_config
 from repro.models.model_zoo import build_model
 from repro.runtime.serve_loop import (
@@ -44,10 +45,6 @@ from repro.runtime.serve_loop import (
     ServingSession,
     ShardedPagedServingSession,
 )
-
-
-def _on_tpu() -> bool:
-    return any(d.platform == "tpu" for d in jax.devices())
 
 
 def _geometry(tier: str) -> dict:
@@ -102,7 +99,8 @@ def _timed_steps(sess, n: int) -> float:
 def _serve_scenario(cfg, model, params, g, *, shared_prefix: bool) -> dict:
     rng = np.random.default_rng(0)
     paged = PagedServingSession(
-        model, params, num_pages=g["num_pages"], page_size=g["page"],
+        model, params, interpret=g["interpret"],
+        num_pages=g["num_pages"], page_size=g["page"],
         block_k=g["block_k"], prefill_chunk=g["chunk"],
         prefix_sharing=shared_prefix,
     )
@@ -181,11 +179,13 @@ def _sharded_scenario(cfg, model, params, g, *, shards: int = 2) -> dict:
         rng.integers(2, cfg.vocab_size, size=n).tolist() for n in g["prompts"]
     ]
     single = PagedServingSession(
-        model, params, num_pages=g["num_pages"], page_size=g["page"],
+        model, params, interpret=g["interpret"],
+        num_pages=g["num_pages"], page_size=g["page"],
         block_k=g["block_k"], prefill_chunk=g["chunk"],
     )
     sharded = ShardedPagedServingSession(
-        model, params, num_pages=g["num_pages"], shards=shards,
+        model, params, interpret=g["interpret"],
+        num_pages=g["num_pages"], shards=shards,
         page_size=g["page"], block_k=g["block_k"], prefill_chunk=g["chunk"],
     )
     r_single = [single.add_request(p) for p in prompts]
@@ -234,7 +234,8 @@ def _dtype_scenario(cfg, model, params, g) -> dict:
     sessions, outs, dts = {}, {}, {}
     for name in ("bf16", "int8"):
         sess = PagedServingSession(
-            model, params, num_pages=g["num_pages"], page_size=g["page"],
+            model, params, interpret=g["interpret"],
+            num_pages=g["num_pages"], page_size=g["page"],
             block_k=g["block_k"], prefill_chunk=g["chunk"], kv_dtype=name,
         )
         rids = [sess.add_request(p) for p in prompts]
@@ -291,7 +292,8 @@ def _speculative_scenario(cfg, model, params, g, *, draft_k: int = 4) -> dict:
 
     def _mk(speculate):
         return PagedServingSession(
-            model, params, num_pages=g["num_pages"], page_size=g["page"],
+            model, params, interpret=g["interpret"],
+            num_pages=g["num_pages"], page_size=g["page"],
             block_k=g["block_k"], prefill_chunk=g["chunk"],
             speculate=speculate, draft_k=draft_k,
         )
@@ -383,7 +385,8 @@ def _failure_recovery_scenario(cfg, model, params, g, *, shards: int = 2) -> dic
 
     def _run(active_plan):
         sess = ShardedPagedServingSession(
-            model, params, num_pages=g["num_pages"], shards=shards,
+            model, params, interpret=g["interpret"],
+            num_pages=g["num_pages"], shards=shards,
             page_size=g["page"], block_k=g["block_k"],
             prefill_chunk=g["chunk"],
         )
@@ -462,7 +465,8 @@ def _multi_tenant_scenario(cfg, model, params, g) -> dict:
 
     def _serve(prefix_cache, kv_dtype=None):
         sess = PagedServingSession(
-            model, params, num_pages=g["num_pages"], page_size=g["page"],
+            model, params, interpret=g["interpret"],
+            num_pages=g["num_pages"], page_size=g["page"],
             block_k=g["block_k"], prefill_chunk=g["chunk"],
             prefix_cache=prefix_cache, kv_dtype=kv_dtype,
         )
@@ -601,7 +605,8 @@ def _multi_tenant_sla_scenario(cfg, model, params, g, *, gen_len: int = 6) -> di
 
     def _run(subs, prefill_budget, kv_dtype=None, deadlines=None):
         sess = PagedServingSession(
-            model, params, num_pages=g["num_pages"], page_size=g["page"],
+            model, params, interpret=g["interpret"],
+            num_pages=g["num_pages"], page_size=g["page"],
             block_k=g["block_k"], prefill_chunk=g["chunk"],
             prefill_budget=prefill_budget, kv_dtype=kv_dtype,
         )
@@ -683,10 +688,16 @@ def _multi_tenant_sla_scenario(cfg, model, params, g, *, gen_len: int = 6) -> di
     return res
 
 
-def run(full: bool = False, smoke: bool = False) -> dict:
+def run(full: bool = False, smoke: bool = False, interpret: bool = False) -> dict:
+    """``interpret`` runs the Pallas kernels in interpret mode on the CPU
+    (mode ``cpu-interpret``: work proxies only); otherwise a TPU is
+    required."""
     tier = "full" if full else ("smoke" if smoke else "default")
-    mode = "tpu" if _on_tpu() else "cpu-interpret"
+    if not interpret:
+        require_tpu()
+    mode = "cpu-interpret" if interpret else "tpu"
     cfg, model, params, g = _build(tier)
+    g["interpret"] = interpret
     report = {"mode": mode, "tier": tier, "scenarios": {}}
     for name, shared in (("ragged", False), ("shared_prefix", True)):
         res = _serve_scenario(cfg, model, params, g, shared_prefix=shared)
@@ -808,8 +819,10 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--interpret", action="store_true",
+                    help="CPU validation: Pallas interpret mode, no TPU")
     args = ap.parse_args()
-    run(full=args.full, smoke=args.smoke)
+    run(full=args.full, smoke=args.smoke, interpret=args.interpret)
 
 
 if __name__ == "__main__":

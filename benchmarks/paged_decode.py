@@ -47,8 +47,14 @@ from repro.kernels.decode_schedule import (
 from repro.runtime.kv_cache import CacheSpec, PagedKVCache
 
 
-def _on_tpu() -> bool:
-    return any(d.platform == "tpu" for d in jax.devices())
+def require_tpu() -> None:
+    """A timed run measures the chip or nothing: no CPU fallback."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(
+            f"no TPU found (jax sees {dev.platform}); pass --interpret for "
+            "a CPU validation run of the work proxies"
+        )
 
 
 def _time(fn, iters: int) -> float:
@@ -364,11 +370,19 @@ def _run_int8_scenario(name, kv_lens, *, hq, dk, dv, page, block_k, iters,
     }
 
 
-def run(full: bool = False, smoke: bool = False, num_splits: int = 2) -> dict:
-    interpret = not _on_tpu()
+def run(
+    full: bool = False,
+    smoke: bool = False,
+    num_splits: int = 2,
+    interpret: bool = False,
+) -> dict:
+    """``interpret`` runs the kernels in Pallas interpret mode on the CPU
+    (mode ``cpu-interpret``); otherwise a TPU is required."""
+    if not interpret:
+        require_tpu()
     tier = "full" if full else ("smoke" if smoke else "default")
     g = _geometry(tier)
-    mode = "tpu" if not interpret else "cpu-interpret"
+    mode = "cpu-interpret" if interpret else "tpu"
 
     report = {
         "bench": "paged_decode",
@@ -512,5 +526,15 @@ if __name__ == "__main__":
         help="tiny interpret-mode shapes for CI (keeps benchmark code green)",
     )
     ap.add_argument("--num-splits", type=int, default=2)
+    ap.add_argument(
+        "--interpret",
+        action="store_true",
+        help="CPU validation: Pallas interpret mode, no TPU",
+    )
     args = ap.parse_args()
-    run(full=args.full, smoke=args.smoke, num_splits=args.num_splits)
+    run(
+        full=args.full,
+        smoke=args.smoke,
+        num_splits=args.num_splits,
+        interpret=args.interpret,
+    )

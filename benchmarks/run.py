@@ -412,6 +412,12 @@ def main() -> None:
         help="tiny interpret-mode [PAGED] geometry (CI)",
     )
     ap.add_argument(
+        "--interpret",
+        action="store_true",
+        help="CPU validation run: Pallas interpret mode (mode "
+        "cpu-interpret); without it [PAGED]/[MODEL-SERVE] need a TPU",
+    )
+    ap.add_argument(
         "--check-regression",
         action="store_true",
         help="fail (exit 1) if [PAGED] tokens/s regressed vs the committed "
@@ -444,13 +450,17 @@ def main() -> None:
         from benchmarks import paged_decode
 
         section("PAGED decode scheduling (queue vs padded, shared prefix)")
-        report = paged_decode.run(full=args.full, smoke=args.smoke)
+        report = paged_decode.run(
+            full=args.full, smoke=args.smoke, interpret=args.interpret
+        )
 
     if "model-serve" not in args.skip:
         from benchmarks import model_serve
 
         section("MODEL-SERVE full-model decode (dense vs paged backend)")
-        ms = model_serve.run(full=args.full, smoke=args.smoke)
+        ms = model_serve.run(
+            full=args.full, smoke=args.smoke, interpret=args.interpret
+        )
         if report is None:  # [PAGED] skipped: still persist/gate this section
             report = {"mode": ms["mode"], "tier": ms["tier"],
                       "scenarios": {}, "prefix_scenarios": {}}

@@ -9,8 +9,13 @@ prompts chunk-prefill into a LayeredPagedKVCache (one block table shared by
 all layers), every decode step builds ONE work-queue schedule reused by all
 L attention layers, a request is forked into a shared-prefix family
 (zero-copy page aliasing + COW), and the greedy tokens are checked exactly
-against the dense ServingSession backend.
+against the dense ServingSession backend.  A CPU demo: the Pallas kernels
+run in interpret mode.
 """
+
+import os
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import numpy as np
@@ -33,7 +38,7 @@ def main() -> None:
 
     paged = PagedServingSession(
         model, params, num_pages=48, page_size=16, block_k=32,
-        prefill_chunk=16, prefix_sharing=True,
+        prefill_chunk=16, prefix_sharing=True, interpret=True,
     )
     dense = ServingSession(model, params, batch_size=4, max_len=128)
 

@@ -54,9 +54,11 @@ def main() -> None:
         rng.integers(2, cfg.vocab_size, size=n).tolist() for n in (4, 7)
     ]
 
-    single = PagedServingSession(model, params, num_pages=64, page_size=8)
+    single = PagedServingSession(
+        model, params, num_pages=64, page_size=8, interpret=True
+    )
     sharded = ShardedPagedServingSession(
-        model, params, num_pages=64, mesh=mesh, page_size=8
+        model, params, num_pages=64, mesh=mesh, page_size=8, interpret=True
     )
     print(
         f"mesh 2x2: {sharded.num_shards} data shards x "

@@ -1,16 +1,7 @@
-"""Compatibility across jax 0.4.x/0.5.x API drift.
+"""Thin helpers over the jax APIs the mesh and collective code share.
 
-The container pins whatever jax ships with the image; the repo must run on
-all of them.  Three surfaces have drifted:
-
-* ``shard_map``: top-level ``jax.shard_map`` (new, ``check_vma=``) vs
-  ``jax.experimental.shard_map.shard_map`` (old, ``check_rep=``).
-* ``jax.sharding.AxisType`` + ``jax.make_mesh(..., axis_types=)``: newer
-  jax wants explicit axis types; older jax has neither.
-* ``Compiled.cost_analysis()``: dict (new) vs single-element list of dicts
-  (old).
-
-Kernel-side compat (Pallas CompilerParams) lives in ``kernels/compat.py``.
+Kernel-side names (Pallas ``CompilerParams``, memory spaces) live in
+``kernels/compat.py``.
 """
 
 from __future__ import annotations
@@ -19,37 +10,15 @@ import jax
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` with the replication-check kwarg of either era."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check_vma,
-            )
-        except TypeError:  # pre-check_vma signature
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check_vma,
-            )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    """``jax.shard_map`` with the replication check off by default."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
     )
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the concept exists."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every jax version."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0]
-    return cost
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )

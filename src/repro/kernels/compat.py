@@ -1,16 +1,13 @@
-"""Version compatibility for the Pallas TPU API surface.
+"""The Pallas TPU names every kernel module takes from one place.
 
-jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams`` (and back
-again across 0.4.x/0.5.x releases).  Every kernel module imports the name from
-here so the repo runs on whichever jax the environment bakes in.
+``ANY`` is the memory space of an input the kernel leaves in HBM and stages
+itself with explicit DMAs (the page pools and the contiguous latent cache).
 """
 
 from __future__ import annotations
 
+from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-if CompilerParams is None:  # pragma: no cover - very old jax
-    raise ImportError("no Pallas TPU CompilerParams class found in this jax")
+CompilerParams = pltpu.CompilerParams
+ANY = pl.ANY

@@ -35,8 +35,9 @@ DEFAULT_BLOCK_K = 512
 
 def _gqa_decode_kernel(
     kv_len_ref,  # (B,) int32
-    q_pos_ref,  # (B, G) int32
+    q_pos_min_ref,  # (B,) int32 earliest query position per request
     q_ref,  # (G, Dh)
+    q_pos_ref,  # (G, 1) int32 absolute position per query row (VMEM)
     k_ref,  # (Bk, Dh)
     v_ref,  # (Bk, Dh)
     o_ref,  # (G, Dh)
@@ -74,8 +75,7 @@ def _gqa_decode_kernel(
     if window is not None:
         # Whole-block skip outside the sliding window (min query position
         # bounds the earliest key any row can see).
-        min_qpos = jnp.min(q_pos_ref[b])
-        needed &= (start + block_k) > (min_qpos - window + 1)
+        needed &= (start + block_k) > (q_pos_min_ref[b] - window + 1)
 
     @pl.when(needed)
     def _compute():
@@ -89,10 +89,10 @@ def _gqa_decode_kernel(
         s = jnp.clip(s, -numerics.M_CLAMP, numerics.M_CLAMP)
 
         k_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        q_pos = q_pos_ref[b]
-        mask = (k_pos < k_len) & (k_pos <= q_pos[:, None])
+        q_pos = q_pos_ref[...]
+        mask = (k_pos < k_len) & (k_pos <= q_pos)
         if window is not None:
-            mask &= k_pos > q_pos[:, None] - window
+            mask &= k_pos > q_pos - window
         s = jnp.where(mask, s, -jnp.inf)
 
         m_prev = m_ref[...]
@@ -172,6 +172,7 @@ def gqa_decode_rows(
         grid=(b, hkv, n_blocks),
         in_specs=[
             pl.BlockSpec((None, None, g, dh), lambda bb, hh, ii, *_: (bb, hh, 0, 0)),
+            pl.BlockSpec((None, g, 1), lambda bb, hh, ii, *_: (bb, 0, 0)),
             pl.BlockSpec(
                 (None, None, block_k, dh), lambda bb, hh, ii, *_: (bb, hh, ii, 0)
             ),
@@ -207,4 +208,11 @@ def gqa_decode_rows(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(kv_len.astype(jnp.int32), q_pos.astype(jnp.int32), q, k, v)
+    )(
+        kv_len.astype(jnp.int32),
+        jnp.min(q_pos, axis=1).astype(jnp.int32),
+        q,
+        q_pos.astype(jnp.int32)[..., None],
+        k,
+        v,
+    )

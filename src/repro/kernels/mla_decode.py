@@ -43,7 +43,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels.compat import ANY, CompilerParams
 
 from repro.core import numerics
 
@@ -52,6 +52,27 @@ DEFAULT_BLOCK_K = 512
 # 128 keeps the (G x 128 x 576) strip MXU-aligned and equals the paged
 # kernel's page size, so both kernels share the same pipeline shape.
 SUB_K = 128
+LANES = 128
+# Most query rows one kernel program holds in VMEM (q, output, lse and the
+# f32 accumulator); the queue and combine kernels run more as grid tiles.
+ROW_TILE = 512
+
+
+def lane_pad(n: int) -> int:
+    """``n`` rounded up to whole 128-lane tiles: the minor-dim width a TPU
+    array occupies in memory, and the only widths a DMA may slice."""
+    return -(-n // LANES) * LANES
+
+
+def row_tiling(g: int) -> tuple[int, int]:
+    """``(tiles, rows per tile)`` for ``g`` query rows.
+
+    At most :data:`ROW_TILE` rows per tile, spread evenly; when there are
+    several tiles each is a multiple of 16 rows (the bf16 sublane tile)."""
+    n = -(-g // ROW_TILE)
+    if n == 1:
+        return 1, g
+    return n, -(-g // (16 * n)) * 16
 
 
 def decode_state_scratch(g: int, d_v: int) -> list:
@@ -106,9 +127,10 @@ def preload_block_scores(
     are zero-filled in VMEM instead of DMA'd.  Returns the concatenated
     (G, n_sub*sub_k) FP32 score strip.
 
-    **Quantized side-channel** (int8 latent pages): when ``scale_view`` — a
-    ``(1, n_sub*sub_k)`` FP32 staging slot — is given, each sub-tile's
-    per-row dequant scales (``scale_src(j)``, a ``(1, sub_k)`` HBM slice)
+    **Quantized side-channel** (int8 latent pages): when ``scale_view`` — an
+    ``(n_sub, SW)`` FP32 staging slot, one row per sub-tile — is given, each
+    sub-tile's per-row dequant scales (``scale_src(j)``, a ``(1, SW)`` HBM
+    row whose first ``sub_k`` lanes are live, see :func:`block_scale_row`)
     ride the same one-ahead DMA pipeline on their own semaphores, and the
     sub-tile's raw int8 score strip is multiplied by them right after the
     matmul — the dequant costs a (G, sub_k) VPU multiply inside the
@@ -126,9 +148,7 @@ def preload_block_scores(
 
     def sdma(j):
         return pltpu.make_async_copy(
-            scale_src(j),
-            scale_view.at[:, pl.ds(j * sub_k, sub_k)],
-            scale_sem.at[j],
+            scale_src(j), scale_view.at[pl.ds(j, 1), :], scale_sem.at[j]
         )
 
     def issue(j):
@@ -149,8 +169,8 @@ def preload_block_scores(
                 (sub_k, kv_view.shape[1]), kv_view.dtype
             )
             if scale_view is not None:
-                scale_view[:, pl.ds(j * sub_k, sub_k)] = jnp.zeros(
-                    (1, sub_k), scale_view.dtype
+                scale_view[pl.ds(j, 1), :] = jnp.zeros(
+                    (1, scale_view.shape[1]), scale_view.dtype
                 )
 
     def wait(j):
@@ -179,8 +199,25 @@ def preload_block_scores(
         )
         if scale_view is not None:
             # Per-key-row dequant: score column j scales by its row's σ.
-            s_j = s_j * scale_view[:, pl.ds(j * sub_k, sub_k)]
+            s_j = s_j * _scale_strip(scale_view, j, sub_k)
         parts.append(s_j)
+    return jnp.concatenate(parts, axis=1) if n_sub > 1 else parts[0]
+
+
+def _scale_strip(scale_view, j, sub_k):
+    """Sub-tile ``j``'s ``(1, sub_k)`` scales out of its staged row."""
+    row = scale_view[pl.ds(j, 1), :]
+    return row if row.shape[1] == sub_k else row[:, :sub_k]
+
+
+def block_scale_row(scale_view, *, n_sub, sub_k):
+    """The block's ``(1, n_sub*sub_k)`` per-key-row scales, in key order.
+
+    Scale pools keep one lane-padded row per page (``SW`` lanes, a
+    multiple of 128, of which the first ``sub_k`` are live): a DMA may
+    only move whole 128-lane tiles, so a page's scales cannot land at an
+    unaligned lane offset of one block-wide row."""
+    parts = [_scale_strip(scale_view, j, sub_k) for j in range(n_sub)]
     return jnp.concatenate(parts, axis=1) if n_sub > 1 else parts[0]
 
 
@@ -210,7 +247,7 @@ def prefetch_next_first_subtile(
         if scale_view_next is not None:
             pltpu.make_async_copy(
                 scale_src0(),
-                scale_view_next.at[:, pl.ds(0, sub_k)],
+                scale_view_next.at[pl.ds(0, 1), :],
                 scale_sem.at[0],
             ).start()
 
@@ -298,9 +335,9 @@ def finalize_decode(o_ref, acc_ref, l_ref, s16_ref, *, variant: str):
 def _mla_decode_kernel(
     # scalar prefetch
     kv_len_ref,  # (B,) int32
-    q_pos_ref,  # (B, G) int32 absolute positions per query row
     # inputs
     q_ref,  # (G, Dk) bf16
+    q_pos_ref,  # (G, 1) int32 absolute position per query row (VMEM)
     c_hbm,  # (B, S_pad, Dk) bf16 latent cache, resident in HBM (ANY)
     # outputs
     o_ref,  # (G, Dv)
@@ -372,8 +409,7 @@ def _mla_decode_kernel(
         s = jnp.clip(s, -numerics.M_CLAMP, numerics.M_CLAMP)
 
         k_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        q_pos = q_pos_ref[b]  # (G,)
-        mask = (k_pos < k_len) & (k_pos <= q_pos[:, None])
+        mask = (k_pos < k_len) & (k_pos <= q_pos_ref[...])
         s = jnp.where(mask, s, -jnp.inf)
 
         decode_block_update(
@@ -420,19 +456,27 @@ def mla_decode_rows(
     block_k = min(block_k, -(-max(s, 1) // sub_k) * sub_k)
     if block_k % sub_k:
         raise ValueError(f"block_k={block_k} must be a multiple of {sub_k}")
+    # Rows pad to a block multiple and to whole 128-lane tiles: a sub-tile
+    # DMA may not slice 576 of a row's 640 lanes.  Zero lanes in q and the
+    # cache add nothing to the scores.
     pad = (-s) % block_k
-    if pad:
-        c_kv = jnp.pad(c_kv, ((0, 0), (0, pad), (0, 0)))
+    lanes = lane_pad(d_k) - d_k
+    if pad or lanes:
+        c_kv = jnp.pad(c_kv, ((0, 0), (0, pad), (0, lanes)))
+    if lanes:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, lanes)))
+        d_k += lanes
     n_blocks = c_kv.shape[1] // block_k
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(b, n_blocks),
         in_specs=[
             pl.BlockSpec((None, g, d_k), lambda bb, ii, *_: (bb, 0, 0)),
+            pl.BlockSpec((None, g, 1), lambda bb, ii, *_: (bb, 0, 0)),
             # The latent cache stays in HBM; the kernel's preload pipeline
             # stages sub-tiles into VMEM scratch with explicit DMAs.
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=ANY),
         ],
         out_specs=pl.BlockSpec((None, g, d_v), lambda bb, ii, *_: (bb, 0, 0)),
         scratch_shapes=decode_state_scratch(g, d_v)
@@ -460,4 +504,4 @@ def mla_decode_rows(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(kv_len.astype(jnp.int32), q_pos.astype(jnp.int32), q, c_kv)
+    )(kv_len.astype(jnp.int32), q, q_pos.astype(jnp.int32)[..., None], c_kv)

@@ -12,7 +12,7 @@ softmax-weighted average
 — independent of how the KV was partitioned, and variant-agnostic (both the
 AMLA and base finalizers divide their scaling out before writing partials).
 
-The kernel runs a ``(B, num_splits)`` grid, sequential over splits, with the
+The kernel runs a ``(B, row tiles, num_splits)`` grid, sequential over splits, with the
 running ``(acc, m, w)`` combine state in VMEM scratch — the same
 scratch-carried-state pattern as the decode kernels, just over split slots
 instead of KV blocks.  Each request's partial blocks are fetched via a
@@ -32,6 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.compat import CompilerParams
+from repro.kernels.mla_decode import row_tiling
 
 # Finite stand-in for -inf in the running max so that fully-empty slots
 # (lse == -inf) contribute exp(-inf - BIG_NEG) == 0 instead of NaN.
@@ -53,7 +54,7 @@ def _combine_kernel(
     w_ref,  # (G, 1) f32 running weight sum
 ):
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -72,7 +73,7 @@ def _combine_kernel(
         w_ref[...] = w_ref[...] * alpha + w
         m_ref[...] = m_new
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
         w = w_ref[...]
         safe = jnp.where(w > 0, w, 1.0)
@@ -88,36 +89,45 @@ def combine_split_partials(
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    """Merge per-slot split-KV partials into ``(B, G, Dv)`` outputs."""
+    """Merge per-slot split-KV partials into ``(B, G, Dv)`` outputs.
+
+    Rows run in VMEM-sized tiles (``mla_decode.row_tiling``); padded rows
+    carry ``lse == -inf`` and are sliced off."""
     d, g, d_v = o_part.shape
     b, s = dest_table.shape
+    n_tiles, tile_g = row_tiling(g)
+    g_pad = n_tiles * tile_g
+    if g_pad != g:
+        o_part = jnp.pad(o_part, ((0, 0), (0, g_pad - g), (0, 0)))
+        lse = jnp.pad(
+            lse, ((0, 0), (0, g_pad - g), (0, 0)), constant_values=-jnp.inf
+        )
+
+    def part(bb, r, jj, dest_ref, ns_ref):
+        return dest_ref[bb, jj], r, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, s),
+        grid=(b, n_tiles, s),
         in_specs=[
-            pl.BlockSpec(
-                (None, g, d_v),
-                lambda bb, jj, dest_ref, ns_ref: (dest_ref[bb, jj], 0, 0),
-            ),
-            pl.BlockSpec(
-                (None, g, 1),
-                lambda bb, jj, dest_ref, ns_ref: (dest_ref[bb, jj], 0, 0),
-            ),
+            pl.BlockSpec((None, tile_g, d_v), part),
+            pl.BlockSpec((None, tile_g, 1), part),
         ],
-        out_specs=pl.BlockSpec((None, g, d_v), lambda bb, jj, *_: (bb, 0, 0)),
+        out_specs=pl.BlockSpec(
+            (None, tile_g, d_v), lambda bb, r, jj, *_: (bb, r, 0)
+        ),
         scratch_shapes=[
-            pltpu.VMEM((g, d_v), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((tile_g, d_v), jnp.float32),
+            pltpu.VMEM((tile_g, 1), jnp.float32),
+            pltpu.VMEM((tile_g, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _combine_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, g, d_v), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, g_pad, d_v), jnp.float32),
         compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(
@@ -126,6 +136,7 @@ def combine_split_partials(
         o_part,
         lse,
     )
+    return out[:, :g]
 
 
 def combine_hetero_partials(

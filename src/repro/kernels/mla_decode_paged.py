@@ -76,7 +76,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels.compat import ANY, CompilerParams
 
 from repro.core import numerics
 from repro.kernels import mla_decode as _mla
@@ -136,14 +136,12 @@ class CacheSpec:
     def quantized(self) -> bool:
         return self.dtype == jnp.int8
 
-    def bytes_per_row(self, width: int) -> int:
-        """HBM bytes one latent row costs, scales included."""
-        n = width * jnp.dtype(self.dtype).itemsize
-        return n + (4 if self.quantized else 0)
-
     def bytes_per_page(self, page_size: int, width: int) -> int:
-        """HBM bytes one page DMA moves (data strip + scale strip)."""
-        return page_size * self.bytes_per_row(width)
+        """HBM bytes one page's DMAs move: the data strip and, for int8,
+        the scale strip, each padded to whole 128-lane tiles (a 576-wide
+        row moves 640 lanes; see ``kv_cache.PagedKVCache``)."""
+        n = page_size * _mla.lane_pad(width) * jnp.dtype(self.dtype).itemsize
+        return n + (_mla.lane_pad(page_size) * 4 if self.quantized else 0)
 
 
 def clamp_tail_pages(
@@ -180,10 +178,10 @@ def clamp_tail_pages(
 def _mla_decode_paged_kernel(
     # scalar prefetch
     kv_len_ref,  # (B,) int32
-    q_pos_ref,  # (B, G) int32 absolute positions per query row
     block_table_ref,  # (B, W) int32 logical page -> physical page id
     # inputs
     q_ref,  # (G, Dk) bf16
+    q_pos_ref,  # (G, 1) int32 absolute position per query row (VMEM)
     page_ref,  # (page_size, Dk) bf16  (physical page selected by index_map)
     # outputs
     o_ref,  # (G, Dv)
@@ -233,8 +231,7 @@ def _mla_decode_paged_kernel(
         s = jnp.clip(s, -numerics.M_CLAMP, numerics.M_CLAMP)
 
         k_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        q_pos = q_pos_ref[b]  # (G,)
-        mask = (k_pos < k_len) & (k_pos <= q_pos[:, None])
+        mask = (k_pos < k_len) & (k_pos <= q_pos_ref[...])
         s = jnp.where(mask, s, -jnp.inf)
 
         _mla.decode_block_update(
@@ -290,13 +287,14 @@ def mla_decode_paged_rows(
     block_tables = clamp_tail_pages(block_tables, kv_len, page_size, num_pages)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=2,
         grid=(b, w),
         in_specs=[
             pl.BlockSpec((None, g, d_k), lambda bb, ii, *_: (bb, 0, 0)),
+            pl.BlockSpec((None, g, 1), lambda bb, ii, *_: (bb, 0, 0)),
             pl.BlockSpec(
                 (None, page_size, d_k),
-                lambda bb, ii, kv_len_ref, q_pos_ref, bt_ref: (
+                lambda bb, ii, kv_len_ref, bt_ref: (
                     bt_ref[bb, ii],
                     0,
                     0,
@@ -324,9 +322,9 @@ def mla_decode_paged_rows(
         interpret=interpret,
     )(
         kv_len,
-        q_pos.astype(jnp.int32),
         block_tables,
         q,
+        q_pos.astype(jnp.int32)[..., None],
         kv_pages,
     )
 
@@ -339,7 +337,6 @@ def mla_decode_paged_rows(
 def _mla_decode_queue_kernel(
     # scalar prefetch
     kv_len_ref,  # (B,) int32
-    q_pos_ref,  # (B, G) int32
     bt_ref,  # (B, W) int32 logical page -> physical page id
     ireq_ref,  # (N,) int32 request index per work item
     iblk_ref,  # (N,) int32 kv-block index within the request
@@ -347,10 +344,11 @@ def _mla_decode_queue_kernel(
     ifst_ref,  # (N,) int32 1 on a dest's first item
     ilst_ref,  # (N,) int32 1 on a dest's last item
     ivld_ref,  # (N,) int32 0 for queue padding
-    # inputs: q_ref, pages_hbm[, scales_hbm], then outputs and scratch —
-    # the quantized variant splices the scale pool input and its staging
-    # scratch in, so the tail is unpacked by arity below.
-    q_ref,  # (G, Dk) bf16 (block selected by item_req)
+    # inputs: q_ref, q_pos_ref, pages_hbm[, scales_hbm], then outputs and
+    # scratch — the quantized variant splices the scale pool input and its
+    # staging scratch in, so the tail is unpacked by arity below.
+    q_ref,  # (Gt, Dk) bf16 row tile (block selected by item_req)
+    q_pos_ref,  # (Gt, 1) int32 absolute position per query row (VMEM)
     pages_hbm,  # (P, page_size, Dk) page pool, resident in HBM (ANY)
     *rest,
     scale: float,
@@ -362,8 +360,8 @@ def _mla_decode_queue_kernel(
     quantized: bool,
 ):
     if quantized:
-        # scales_hbm: (P, page_size) f32 per-row dequant scales (ANY);
-        # scale_blk_ref: (2, 1, block_k) f32 double-buffered staging;
+        # scales_hbm: (P, SW) f32 per-row dequant scales, lane-padded (ANY);
+        # scale_blk_ref: (2, n_sub, SW) f32 double-buffered staging;
         # scale_sem: one DMA semaphore per page of the block.
         (scales_hbm, o_ref, lse_ref, acc_ref, m_ref, l_ref, n_ref,
          gamma_ref, s16_ref, kv_blk_ref, sem, scale_blk_ref,
@@ -373,7 +371,9 @@ def _mla_decode_queue_kernel(
          s16_ref, kv_blk_ref, sem) = rest
         scales_hbm = scale_blk_ref = scale_sem = None
 
-    t = pl.program_id(0)
+    # Grid is (row tile, work item): each row tile walks the whole queue.
+    t = pl.program_id(1)
+    n_items = pl.num_programs(1)
     req = ireq_ref[t]
     blk = iblk_ref[t]
     first = ifst_ref[t]
@@ -396,7 +396,7 @@ def _mla_decode_queue_kernel(
     # is exactly "the previous step prefetched this item's first page" (its
     # condition below was this item's validity), and a valid item's first
     # page always intersects kv_len by construction.
-    t_next = jnp.minimum(t + 1, pl.num_programs(0) - 1)
+    t_next = jnp.minimum(t + 1, n_items - 1)
     cur = jax.lax.rem(t, 2)
 
     @pl.when(valid == 1)
@@ -413,9 +413,7 @@ def _mla_decode_queue_kernel(
             return pages_hbm.at[bt_ref[req, first_page + j]]
 
         def scale_src(j):
-            return scales_hbm.at[
-                pl.ds(bt_ref[req, first_page + j], 1), :
-            ]
+            return scales_hbm.at[pl.ds(bt_ref[req, first_page + j], 1), :]
 
         s = _mla.preload_block_scores(
             q_ref, kv_view, n_sub=n_sub, sub_k=page_size,
@@ -427,7 +425,7 @@ def _mla_decode_queue_kernel(
         # Cross-step lookahead: start the next work item's first-page gather
         # (and, quantized, its scale strip) now so the copies overlap this
         # item's state update.
-        next_cond = (t + 1 < pl.num_programs(0)) & (ivld_ref[t_next] == 1)
+        next_cond = (t + 1 < n_items) & (ivld_ref[t_next] == 1)
         next_pid = lambda: bt_ref[ireq_ref[t_next], iblk_ref[t_next] * n_sub]
         _mla.prefetch_next_first_subtile(
             lambda: pages_hbm.at[next_pid()],
@@ -451,15 +449,16 @@ def _mla_decode_queue_kernel(
         s = jnp.clip(s, -numerics.M_CLAMP, numerics.M_CLAMP)
 
         k_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        q_pos = q_pos_ref[req]  # (G,)
-        mask = (k_pos < k_len) & (k_pos <= q_pos[:, None])
+        mask = (k_pos < k_len) & (k_pos <= q_pos_ref[...])
         s = jnp.where(mask, s, -jnp.inf)
 
         _mla.decode_block_update(
             s, kv_view[...],
             acc_ref, m_ref, l_ref, n_ref, gamma_ref, s16_ref,
             d_v=d_v, variant=variant, mm_dtype=q_ref.dtype,
-            kv_scale=None if scale_view is None else scale_view[...],
+            kv_scale=None if scale_view is None else _mla.block_scale_row(
+                scale_view, n_sub=n_sub, sub_k=page_size
+            ),
         )
 
     @pl.when((last == 1) & (valid == 1))
@@ -539,31 +538,50 @@ def mla_decode_paged_queue_rows(
         block_tables, kv_len, page_size, num_pages
     )
     n_items = item_req.shape[0]
+    # Query rows run in tiles of at most ROW_TILE (a 32-token prefill chunk
+    # at 128 heads is 4096 rows); padded rows carry q_pos = -1, which masks
+    # every key, and are sliced off below.
+    n_tiles, tile_g = _mla.row_tiling(g)
+    g_pad = n_tiles * tile_g
+    q_pos = q_pos.astype(jnp.int32)
+    if g_pad != g:
+        q = jnp.pad(q, ((0, 0), (0, g_pad - g), (0, 0)))
+        q_pos = jnp.pad(q_pos, ((0, 0), (0, g_pad - g)), constant_values=-1)
 
+    # index_maps see (row tile, item, *scalar refs); refs[2] is item_req and
+    # refs[4] item_dest.
     data_specs = [
-        pl.BlockSpec((None, g, d_k), lambda t, *refs: (refs[3][t], 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        pl.BlockSpec((None, tile_g, d_k), lambda r, t, *refs: (refs[2][t], r, 0)),
+        pl.BlockSpec((None, tile_g, 1), lambda r, t, *refs: (refs[2][t], r, 0)),
+        pl.BlockSpec(memory_space=ANY),
     ]
     extra_scratch = []
     if quantized:
         # The scale pool rides along in HBM; strips are staged by explicit
         # DMA exactly like the page data, with their own semaphores.
-        data_specs.append(pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY))
+        data_specs.append(pl.BlockSpec(memory_space=ANY))
         extra_scratch = [
-            pltpu.VMEM((2, 1, block_k), jnp.float32),
+            pltpu.VMEM(
+                (2, block_k // page_size, kv_scales.shape[1]), jnp.float32
+            ),
             pltpu.SemaphoreType.DMA((block_k // page_size,)),
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=9,
-        # Flat 1D queue; scratch-carried state makes it sequential
-        # ("arbitrary"), which is what lets one dest span several items.
-        grid=(n_items,),
+        num_scalar_prefetch=8,
+        # Flat queue per row tile; scratch-carried state makes the queue
+        # axis sequential ("arbitrary"), which is what lets one dest span
+        # several items.  Row tiles are independent.
+        grid=(n_tiles, n_items),
         in_specs=data_specs,
         out_specs=[
-            pl.BlockSpec((None, g, d_v), lambda t, *refs: (refs[5][t], 0, 0)),
-            pl.BlockSpec((None, g, 1), lambda t, *refs: (refs[5][t], 0, 0)),
+            pl.BlockSpec(
+                (None, tile_g, d_v), lambda r, t, *refs: (refs[4][t], r, 0)
+            ),
+            pl.BlockSpec(
+                (None, tile_g, 1), lambda r, t, *refs: (refs[4][t], r, 0)
+            ),
         ],
-        scratch_shapes=_mla.decode_state_scratch(g, d_v)
+        scratch_shapes=_mla.decode_state_scratch(tile_g, d_v)
         + [
             # Double-buffered so the cross-step lookahead can stage the next
             # item's first page while this item is still being read.
@@ -582,23 +600,22 @@ def mla_decode_paged_queue_rows(
         softcap=softcap,
         quantized=quantized,
     )
-    inputs = [q, kv_pages]
+    inputs = [q, q_pos[..., None], kv_pages]
     if quantized:
         inputs.append(kv_scales.astype(jnp.float32))
-    return pl.pallas_call(
+    o, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((num_dest_slots, g, d_v), jnp.float32),
-            jax.ShapeDtypeStruct((num_dest_slots, g, 1), jnp.float32),
+            jax.ShapeDtypeStruct((num_dest_slots, g_pad, d_v), jnp.float32),
+            jax.ShapeDtypeStruct((num_dest_slots, g_pad, 1), jnp.float32),
         ],
         compiler_params=CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(
         kv_len,
-        q_pos.astype(jnp.int32),
         block_tables,
         item_req.astype(jnp.int32),
         item_block.astype(jnp.int32),
@@ -608,6 +625,7 @@ def mla_decode_paged_queue_rows(
         item_valid.astype(jnp.int32),
         *inputs,
     )
+    return o[:, :g], lse[:, :g]
 
 
 # --------------------------------------------------------------------------- #

@@ -97,11 +97,12 @@ def _validate_paged_geometry(
                 f"kv_scales given but kv_pages dtype is {kv_pages.dtype} — "
                 f"scale pools accompany int8 pools only"
             )
-        if kv_scales.shape != (num_pages, page_size):
+        lanes = _mla.lane_pad(page_size)
+        if kv_scales.shape not in ((num_pages, page_size), (num_pages, lanes)):
             raise ValueError(
                 f"kv_scales must be (num_pages={num_pages}, "
-                f"page_size={page_size}) — one fp32 scale per page row; "
-                f"got {kv_scales.shape}"
+                f"page_size={page_size}) — one fp32 scale per page row, "
+                f"optionally lane-padded to {lanes}; got {kv_scales.shape}"
             )
     if block_tables.ndim != 2 or block_tables.shape[0] != b:
         raise ValueError(
@@ -115,10 +116,11 @@ def _validate_paged_geometry(
             "use PagedKVCache.block_table, which pads empty sequences to "
             "width 1"
         )
-    if q.shape[-1] != dk_pages:
+    if dk_pages not in (q.shape[-1], _mla.lane_pad(q.shape[-1])):
         raise ValueError(
             f"q feature width {q.shape[-1]} != page row width {dk_pages}; "
-            f"queries and the latent page pool must share D_k"
+            f"queries and the latent page pool must share D_k (the pool "
+            f"may pad it to whole 128-lane tiles)"
         )
     if block_k is not None and (block_k < page_size or block_k % page_size):
         raise ValueError(
@@ -303,6 +305,9 @@ def mla_decode_paged(
         q_pos = jnp.full((b, sq), cap, jnp.int32)  # no causal restriction
     rows_pos = jnp.repeat(q_pos, hq, axis=1)  # (B, Sq*Hq)
     q_rows = q.reshape(b, sq * hq, dk).astype(compute_dtype)
+    if kv_pages.shape[-1] != dk:
+        # Lane-padded pool rows: zero query lanes add nothing to the scores.
+        q_rows = jnp.pad(q_rows, ((0, 0), (0, 0), (0, kv_pages.shape[-1] - dk)))
 
     if return_partials and (scheduler != "queue" or prefix_sharing):
         raise ValueError(

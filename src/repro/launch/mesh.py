@@ -50,15 +50,27 @@ def make_serving_mesh(spec: str = "1x1"):
     Raises with a remediation hint when the host exposes fewer devices than
     the spec needs — on CPU, set
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` *before* jax
-    initializes its backends.
+    initializes its backends; on an accelerator the mesh needs that many
+    real chips.
     """
     data, model = parse_mesh_spec(spec)
-    avail = len(jax.devices())
-    if avail < data * model:
+    devices = jax.devices()
+    need = data * model
+    if len(devices) < need:
+        platform = devices[0].platform
+        if platform == "cpu":
+            hint = (
+                "on CPU export XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={need} before "
+                "jax initializes (or pick a smaller mesh)"
+            )
+        else:
+            hint = (
+                f"{devices[0].device_kind} chips are real devices — run on "
+                f"a host with {need} of them (or pick a smaller mesh)"
+            )
         raise ValueError(
-            f"mesh {spec!r} needs {data * model} devices but only {avail} "
-            "are visible; on CPU export XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={data * model} before "
-            "jax initializes (or pick a smaller mesh)"
+            f"mesh {spec!r} needs {need} devices but only {len(devices)} "
+            f"{platform} devices are visible; {hint}"
         )
     return jax_compat.make_mesh((data, model), ("data", "model"))

@@ -8,18 +8,20 @@ chunked prefill-into-pages and one decode schedule per step shared by all
 layers.  Greedy outputs are identical across backends (the parity suite
 ``tests/test_paged_model_serve.py`` pins this exactly).
 
-Runs a reduced config on CPU (examples use it); the same sessions +
-sharded serve fns drive the full configs on a real mesh.
+The paged kernels compile for the TPU.  On a CPU (``JAX_PLATFORMS=cpu``)
+pass ``--interpret`` to run them in Pallas interpret mode; ``--mesh`` then
+forces that many host devices.  ``chip_smoke.py`` at the repo root drives
+this path at full model width on one chip.
 
-Usage:
+Usage (CPU, reduced config):
     PYTHONPATH=src python -m repro.launch.serve --cache paged --smoke \
-        --requests 6 --gen-len 16
+        --interpret --requests 6 --gen-len 16
     PYTHONPATH=src python -m repro.launch.serve --cache paged --smoke \
-        --shared-prefix   # forked system-prompt demo
+        --interpret --shared-prefix   # forked system-prompt demo
     PYTHONPATH=src python -m repro.launch.serve --cache paged --smoke \
-        --speculate ngram --draft-k 4   # draft-verify speculative decode
+        --interpret --speculate ngram --draft-k 4   # speculative decode
     PYTHONPATH=src python -m repro.launch.serve --cache paged --smoke \
-        --prefill-budget 64 --priority-classes 2,0,1   # SLA interleaving
+        --interpret --prefill-budget 64 --priority-classes 2,0,1
 
 The paged backend needs an MLA geometry; with no explicit ``--arch`` it
 serves the paper's (``deepseek-v2-mla``), while dense defaults to
@@ -34,8 +36,14 @@ import sys
 import time
 
 
+def _cpu_platform() -> bool:
+    """True when jax is pinned to the CPU (``JAX_PLATFORMS=cpu``)."""
+    return os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip() == "cpu"
+
+
 def _ensure_cpu_mesh_devices(n: int) -> None:
-    """Ask XLA's host platform for ``n`` CPU devices before backends init.
+    """On a CPU-only run, ask XLA's host platform for ``n`` devices
+    before backends init; on an accelerator the mesh uses real chips.
 
     Importing repro modules below initializes the jax backend (kernel
     constants touch device state at import), so this must run from
@@ -43,6 +51,8 @@ def _ensure_cpu_mesh_devices(n: int) -> None:
     the ``python -m repro.launch.serve`` entry path.  A pre-set XLA_FLAGS
     carrying the option is respected (e.g. the CI job exports it).
     """
+    if not _cpu_platform():
+        return
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -79,6 +89,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.models.model_zoo import build_model
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.kv_cache import OutOfPagesError
 from repro.runtime.serve_loop import (
     PagedServingSession,
@@ -107,6 +118,7 @@ def _build_session(args, cfg, model, params):
             prefix_cache=args.prefix_cache,
             retain_pages=args.retain_pages,
             prefill_budget=args.prefill_budget,
+            interpret=args.interpret,
         )
     if args.cache == "paged":
         return PagedServingSession(
@@ -124,6 +136,7 @@ def _build_session(args, cfg, model, params):
             prefix_cache=args.prefix_cache,
             retain_pages=args.retain_pages,
             prefill_budget=args.prefill_budget,
+            interpret=args.interpret,
         )
     if args.kv_dtype is not None:
         raise SystemExit("--kv-dtype needs --cache paged (dense caches "
@@ -338,7 +351,7 @@ def _shared_prefix_demo(sess, cfg, seed, gen_len):
         print(f"request {rid} done: {len(out)} tokens: {out[:8]}...")
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None,
                     help="default: qwen1.5-0.5b (dense) / deepseek-v2-mla (paged)")
@@ -384,8 +397,9 @@ def main(argv=None):
     ap.add_argument("--mesh", default=None,
                     help="paged only: DxM serving mesh, e.g. 2x1 — shard "
                     "the page pool + decode queue over D data shards with "
-                    "M-way tensor-parallel heads each (CPU hosts get the "
-                    "devices forced via XLA_FLAGS automatically)")
+                    "M-way tensor-parallel heads each (under "
+                    "JAX_PLATFORMS=cpu the host devices are forced via "
+                    "XLA_FLAGS automatically)")
     ap.add_argument("--chaos", type=int, default=None, metavar="SEED",
                     help="paged only: run the supervised serve loop under a "
                     "seeded FaultPlan (shard loss / slow shard / pool "
@@ -408,8 +422,17 @@ def main(argv=None):
                     "classes assigned round-robin to submitted requests "
                     "(higher admits first; ties by deadline slack then "
                     "submission order); implies the supervised loop")
+    ap.add_argument("--interpret", action="store_true",
+                    help="paged only: run the Pallas kernels in interpret "
+                    "mode (CPU validation); without it they compile for "
+                    "the TPU")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    enable_compile_cache()
 
     if args.priority_classes is not None:
         try:

@@ -320,6 +320,11 @@ def check_paged_compatible(cfg) -> None:
         )
 
 
+# Batch rows of one TPU sublane tile: the MXU pads a matmul's rows to it
+# anyway, so a decode step padded to a multiple costs no extra compute.
+DECODE_ROW_TILE = 8
+
+
 def per_layer_params(params, cfg) -> list[dict]:
     """Unstack scanned group params into an L-element per-layer list.
 
@@ -636,25 +641,39 @@ def lm_decode_step_paged(
         )
 
     bt, kv_len = jnp.asarray(bt), jnp.asarray(kv_len)
-    x = _paged_embed(params["embed"], jnp.asarray(tokens, jnp.int32), cfg=cfg)
-    pos = jnp.asarray(positions)  # (B, S)
+    # The dense per-layer programs run on whole tiles of DECODE_ROW_TILE
+    # batch rows (pad rows are token 0 at position 0; only the kernel and
+    # the cache writes see the B live rows).  XLA fuses a program
+    # differently per batch size, which moves bf16 roundings; padding makes
+    # a request's logits independent of how many others share its step up
+    # to a tile, so a data shard serving 2 requests matches one chip
+    # serving 8 token for token.
+    b = len(rids)
+    b_pad = -(-b // DECODE_ROW_TILE) * DECODE_ROW_TILE
+    tok = np.zeros((b_pad, s), np.int32)
+    tok[:b] = tokens
+    pos = np.zeros((b_pad, s), np.int32)
+    pos[:b] = positions
+    x = _paged_embed(params["embed"], jnp.asarray(tok), cfg=cfg)
+    pos = jnp.asarray(pos)
     # S == 1 keeps the derived-position path (bit-identical traces to the
     # pre-speculation step); S > 1 passes the rows' absolute positions
     # through the explicit multi-row surface.
     q_positions = positions if s > 1 else None
     for l, p_l in enumerate(layers_p):
         lat, q = _paged_attn_inputs(p_l, x, pos, cfg=cfg)
-        cache.write_layer_tokens(
-            l, pids, offs, lat.reshape(len(rids) * s, -1)
-        )
+        # Rows past the b*s live ones (the pad rows) are not written.
+        cache.write_layer_tokens(l, pids, offs, lat.reshape(b_pad * s, -1))
         attn = _paged_attend(
-            q, cache, l, bt, kv_len, cfg=cfg, block_k=block_k,
+            q[:b], cache, l, bt, kv_len, cfg=cfg, block_k=block_k,
             schedule=schedule, q_offset=None, q_positions=q_positions,
             num_splits=num_splits, interpret=interpret,
             compute_dtype=compute_dtype, variant=variant,
             head_shards=head_shards,
         )
+        if b_pad != b:
+            attn = jnp.pad(attn, ((0, b_pad - b),) + ((0, 0),) * (attn.ndim - 1))
         x = _paged_layer_post(p_l, x, attn, cfg=cfg)
     if s == 1:
-        return _paged_logits_at(params, x, jnp.int32(0), cfg=cfg)
-    return _paged_logits_all(params, x, cfg=cfg)
+        return _paged_logits_at(params, x, jnp.int32(0), cfg=cfg)[:b]
+    return _paged_logits_all(params, x, cfg=cfg)[:b]

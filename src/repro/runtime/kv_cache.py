@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.mla_decode import lane_pad
 from repro.kernels.mla_decode_paged import DEFAULT_PAGE_SIZE, CacheSpec
 
 __all__ = [
@@ -131,7 +132,7 @@ def _write_token_rows_one_layer(pages, rows, layer, pids, offs):
             (layer, pids[i], offs[i], 0),
         )
 
-    return jax.lax.fori_loop(0, rows.shape[0], body, pages)
+    return jax.lax.fori_loop(0, pids.shape[0], body, pages)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -180,7 +181,7 @@ def _write_token_rows_one_layer_quant(pages, scales, rows, layer, pids, offs):
         )
         return p, sc
 
-    return jax.lax.fori_loop(0, rows.shape[0], body, (pages, scales))
+    return jax.lax.fori_loop(0, pids.shape[0], body, (pages, scales))
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -214,6 +215,11 @@ class PagedKVCache:
     num_pages:  total pages in the device pool.
     page_size:  latent rows per page.
     width:      row width (576 = 512 latent + 64 rope for DeepSeek MLA).
+                The device pool pads rows to whole 128-lane tiles
+                (:attr:`pool_width`, 640 for MLA) and the scale pool pads
+                each page's scales likewise (:attr:`scale_width`): the
+                kernels' page DMAs may only move whole tiles.  Pad lanes
+                stay zero; writes and reads touch the first ``width``.
     dtype:      storage dtype of the pool (bf16 in serving).  ``jnp.int8``
                 selects the quantized mode: rows are symmetric-quantized
                 on write against a companion per-row fp32 scale pool
@@ -242,6 +248,8 @@ class PagedKVCache:
         self.num_pages = num_pages
         self.page_size = page_size
         self.width = width
+        self.pool_width = lane_pad(width)
+        self.scale_width = lane_pad(page_size)
         self.spec = spec if spec is not None else CacheSpec(dtype=dtype)
         self.dtype = self.spec.dtype
         self.debug = debug
@@ -561,11 +569,13 @@ class PagedKVCache:
     # ------------------------------------------------------------------ #
     def _make_pool(self) -> jax.Array:
         """Allocate the device page pool (layered subclasses override)."""
-        return jnp.zeros((self.num_pages, self.page_size, self.width), self.dtype)
+        return jnp.zeros(
+            (self.num_pages, self.page_size, self.pool_width), self.dtype
+        )
 
     def _make_scale_pool(self) -> jax.Array:
         """Per-page-row fp32 dequant scales (quantized pools only)."""
-        return jnp.zeros((self.num_pages, self.page_size), jnp.float32)
+        return jnp.zeros((self.num_pages, self.scale_width), jnp.float32)
 
     def _pool_copy_page(self, dst_pid: int, src_pid: int) -> None:
         """Device-side page copy (the COW fault path)."""
@@ -695,14 +705,15 @@ class PagedKVCache:
         n = self._seq_len[rid]
         if n == 0:
             return jnp.zeros((0, self.width), self._row_dtype)
+        w, ps = self.width, self.page_size
         if self.quantized:
             parts = [
-                self.pages[pid].astype(jnp.float32)
-                * self.scales[pid][:, None]
+                self.pages[pid, :, :w].astype(jnp.float32)
+                * self.scales[pid, :ps][:, None]
                 for pid in self._seq_pages[rid]
             ]
         else:
-            parts = [self.pages[pid] for pid in self._seq_pages[rid]]
+            parts = [self.pages[pid, :, :w] for pid in self._seq_pages[rid]]
         return jnp.concatenate(parts, axis=0)[:n]
 
 
@@ -754,7 +765,7 @@ class LayeredPagedKVCache(PagedKVCache):
     # -- pool hooks (layer-axis aware) --------------------------------- #
     def _make_pool(self) -> jax.Array:
         return jnp.zeros(
-            (self.num_layers, self.num_pages, self.page_size, self.width),
+            (self.num_layers, self.num_pages, self.page_size, self.pool_width),
             self.dtype,
         )
 
@@ -762,7 +773,7 @@ class LayeredPagedKVCache(PagedKVCache):
         # Scales carry the layer axis too: every layer's latent rows are
         # quantized independently against their own row maxima.
         return jnp.zeros(
-            (self.num_layers, self.num_pages, self.page_size), jnp.float32
+            (self.num_layers, self.num_pages, self.scale_width), jnp.float32
         )
 
     def _pool_copy_page(self, dst_pid: int, src_pid: int) -> None:
@@ -844,7 +855,8 @@ class LayeredPagedKVCache(PagedKVCache):
         device call per layer.  R is one row per request for a plain step,
         or ``B * draft_k`` rows for a speculative verify step (each row
         carries its own page/offset, so a per-request run may cross a page
-        boundary mid-scatter).
+        boundary mid-scatter).  Rows past ``len(pids)`` (the decode step's
+        batch padding) are not written.
         """
         rows = jnp.asarray(rows, self._row_dtype)
         pids = jnp.asarray(pids, jnp.int32)
@@ -859,11 +871,11 @@ class LayeredPagedKVCache(PagedKVCache):
             )
 
     def layer_pages(self, layer: int) -> jax.Array:
-        """The ``(num_pages, page_size, width)`` pool of one layer."""
+        """The ``(num_pages, page_size, pool_width)`` pool of one layer."""
         return self.pages[layer]
 
     def layer_scales(self, layer: int) -> jax.Array | None:
-        """One layer's ``(num_pages, page_size)`` scale pool (None unless
+        """One layer's ``(num_pages, scale_width)`` scale pool (None unless
         quantized) — passed to the kernels alongside :meth:`layer_pages`."""
         return None if self.scales is None else self.scales[layer]
 
@@ -881,11 +893,13 @@ class LayeredPagedKVCache(PagedKVCache):
             return jnp.zeros(shape, self._row_dtype)
         axis = 1 if layer is None else 0
         sel = self.pages if layer is None else self.pages[layer]
+        sel = sel[..., : self.width]
         parts = [sel[:, pid] if layer is None else sel[pid]
                  for pid in self._seq_pages[rid]]
         out = jnp.concatenate(parts, axis=axis)
         if self.quantized:
             ssel = self.scales if layer is None else self.scales[layer]
+            ssel = ssel[..., : self.page_size]
             sparts = [ssel[:, pid] if layer is None else ssel[pid]
                       for pid in self._seq_pages[rid]]
             out = out.astype(jnp.float32) * jnp.concatenate(sparts, axis=axis)[

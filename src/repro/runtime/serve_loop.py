@@ -337,7 +337,7 @@ class PagedServingSession:
         min_group: int = 2,
         prefill_chunk: int = 32,
         max_batch: int | None = None,
-        interpret: bool | None = None,
+        interpret: bool = False,
         dtype=None,
         kv_dtype=None,
         device=None,
@@ -440,11 +440,9 @@ class PagedServingSession:
                 block_tokens=self.block_k,
                 retain_pages=retain_pages,
             )
-        self.interpret = (
-            interpret
-            if interpret is not None
-            else not any(d.platform == "tpu" for d in jax.devices())
-        )
+        # Pallas interpret mode is for CPU validation and must be asked
+        # for; serving compiles the kernels for the chip.
+        self.interpret = bool(interpret)
         # fp32 smoke models keep fp32 kernel precision so paged greedy
         # outputs stay bit-comparable with the dense fp32 backend; bf16
         # serving uses the kernels' native bf16.
@@ -1376,7 +1374,7 @@ class ShardedPagedServingSession:
         min_group: int = 2,
         prefill_chunk: int = 32,
         max_batch: int | None = None,
-        interpret: bool | None = None,
+        interpret: bool = False,
         dtype=None,
         kv_dtype=None,
         speculate: str = "off",

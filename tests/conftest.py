@@ -1,39 +1,25 @@
-"""Shared test harness: tiering (--runslow) + a graceful hypothesis fallback.
+"""Shared test harness: the CPU platform and the ``--runslow`` tier.
 
-Two jobs:
+The suite runs on the CPU: the Pallas kernels run in interpret mode, which
+each test asks for (``interpret=True``).  ``JAX_PLATFORMS`` defaults to
+``cpu`` so jax does not probe for a TPU at first use; with libtpu installed
+and no chip attached that probe stalls for minutes.  What only the TPU
+compiler checks (VMEM limits, lane-aligned DMA slices, scalar-memory loads)
+is covered by ``tests/test_tpu_compile.py``, which compiles the kernels for
+a described v5e without a chip.  On a chip, ``python3 chip_smoke.py`` at the
+repo root serves the model end to end.
 
-1. **Test tiers.**  The ``slow`` marker (registered here and in
-   pyproject.toml) carves the suite into a fast tier-1 run (default,
-   minutes) and the long model/runtime/subprocess tests, opted back in with
-   ``--runslow``.
-
-2. **hypothesis shim.**  The container image does not ship ``hypothesis``
-   (it is an *optional* dev dependency: CI and local runs must not need it).
-   When the real package is absent we install a tiny deterministic stand-in
-   into ``sys.modules`` before test modules import it.  The shim supports
-   exactly the surface this repo uses — ``given`` (kwargs strategies),
-   ``settings(max_examples, deadline)``, ``strategies.floats/integers/
-   sampled_from`` — and replays a fixed, per-test seeded sweep of examples
-   (log-uniform over positive float ranges, linear otherwise, plus the range
-   endpoints), capped at 50 examples so property suites stay in tier 1.
-   With the real hypothesis installed the shim steps aside entirely.
+The ``slow`` marker (registered here and in pyproject.toml) carves the
+suite into the fast tier-1 run (default, minutes) and the long
+model/runtime/subprocess tests, opted back in with ``--runslow``.
 """
 
 from __future__ import annotations
 
-import math
 import os
-import random
-import sys
-import types
-import zlib
 
 import pytest
 
-# The whole suite validates on CPU (Pallas kernels run in interpret mode).
-# On TPU-less images jax otherwise probes for TPU hardware at first use and
-# stalls ~8 minutes on GCP-metadata retries; TPU-hardware validation is a
-# separate, explicit workflow (benchmarks/paged_decode.py --full).
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 # --------------------------------------------------------------------------- #
@@ -63,138 +49,3 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if "slow" in item.keywords:
             item.add_marker(skip_slow)
-
-
-# --------------------------------------------------------------------------- #
-# hypothesis fallback shim
-# --------------------------------------------------------------------------- #
-
-_SHIM_MAX_EXAMPLES = 50
-
-
-class _Strategy:
-    def __init__(self, sample_fn):
-        self._sample_fn = sample_fn
-
-    def sample(self, rng: random.Random):
-        return self._sample_fn(rng)
-
-
-def _floats(
-    min_value=None,
-    max_value=None,
-    allow_nan=True,
-    allow_infinity=True,
-    width=64,
-):
-    lo = -3.0e38 if min_value is None else float(min_value)
-    hi = 3.0e38 if max_value is None else float(max_value)
-
-    endpoints = [lo, hi]
-    for special in (0.0, 1.0, -1.0):
-        if lo <= special <= hi:
-            endpoints.append(special)
-
-    def sample(rng: random.Random):
-        u = rng.random()
-        if u < 0.15:
-            x = rng.choice(endpoints)
-        elif lo > 0 and u < 0.75:
-            # log-uniform: covers ranges like [2^-100, 2^100] sensibly
-            x = math.exp(rng.uniform(math.log(lo), math.log(hi)))
-        elif hi < 0 and u < 0.75:
-            x = -math.exp(rng.uniform(math.log(-hi), math.log(-lo)))
-        else:
-            x = rng.uniform(lo, hi)
-        if width == 32:
-            import numpy as np
-
-            x = float(np.float32(x))
-        return min(max(x, lo), hi)
-
-    return _Strategy(sample)
-
-
-def _integers(min_value=None, max_value=None):
-    lo = -(2**31) if min_value is None else int(min_value)
-    hi = 2**31 - 1 if max_value is None else int(max_value)
-
-    def sample(rng: random.Random):
-        if rng.random() < 0.1:
-            return rng.choice([lo, hi])
-        return rng.randint(lo, hi)
-
-    return _Strategy(sample)
-
-
-def _sampled_from(elements):
-    elements = list(elements)
-
-    def sample(rng: random.Random):
-        return rng.choice(elements)
-
-    return _Strategy(sample)
-
-
-def _shim_given(**strategy_kwargs):
-    for name, strat in strategy_kwargs.items():
-        if not isinstance(strat, _Strategy):
-            raise TypeError(f"shim given() needs shim strategies; got {name}={strat!r}")
-
-    def decorate(fn):
-        cfg = getattr(fn, "_shim_settings", {})
-        n_examples = min(int(cfg.get("max_examples", 25)), _SHIM_MAX_EXAMPLES)
-
-        def wrapper():
-            rng = random.Random(zlib.adler32(fn.__qualname__.encode()))
-            for idx in range(n_examples):
-                kwargs = {k: s.sample(rng) for k, s in strategy_kwargs.items()}
-                try:
-                    fn(**kwargs)
-                except Exception as err:
-                    raise AssertionError(
-                        f"{fn.__name__} failed on shim example #{idx}: {kwargs!r}"
-                    ) from err
-
-        # NOT functools.wraps: pytest would follow __wrapped__ to the
-        # original signature and demand fixtures for the strategy kwargs.
-        wrapper.__name__ = fn.__name__
-        wrapper.__qualname__ = fn.__qualname__
-        wrapper.__doc__ = fn.__doc__
-        wrapper.__module__ = fn.__module__
-        wrapper._shim_given_wrapped = True
-        return wrapper
-
-    return decorate
-
-
-def _shim_settings(**cfg):
-    def decorate(fn):
-        if getattr(fn, "_shim_given_wrapped", False):
-            return fn  # @settings above @given: sweep already built
-        fn._shim_settings = cfg
-        return fn
-
-    return decorate
-
-
-def _install_hypothesis_shim():
-    strategies = types.ModuleType("hypothesis.strategies")
-    strategies.floats = _floats
-    strategies.integers = _integers
-    strategies.sampled_from = _sampled_from
-
-    shim = types.ModuleType("hypothesis")
-    shim.__is_repro_shim__ = True
-    shim.given = _shim_given
-    shim.settings = _shim_settings
-    shim.strategies = strategies
-
-    sys.modules["hypothesis"] = shim
-    sys.modules["hypothesis.strategies"] = strategies
-
-
-try:  # pragma: no cover - exercised implicitly by the import below
-    import hypothesis  # noqa: F401
-except ImportError:
-    _install_hypothesis_shim()

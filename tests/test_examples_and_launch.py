@@ -74,6 +74,7 @@ def test_serve_driver_paged_backend():
     out = run_script(
         [
             "-m", "repro.launch.serve", "--cache", "paged", "--smoke",
+            "--interpret",
             "--requests", "2", "--gen-len", "4", "--batch", "2",
         ]
     )
@@ -86,6 +87,7 @@ def test_serve_driver_shared_prefix_demo():
     out = run_script(
         [
             "-m", "repro.launch.serve", "--cache", "paged", "--smoke",
+            "--interpret",
             "--shared-prefix", "--gen-len", "3",
         ]
     )

@@ -52,6 +52,7 @@ def make_single(model, params, **kw):
     kw.setdefault("page_size", PAGE)
     kw.setdefault("block_k", BLOCK_K)
     kw.setdefault("prefill_chunk", CHUNK)
+    kw.setdefault("interpret", True)
     return PagedServingSession(model, params, **kw)
 
 
@@ -61,6 +62,7 @@ def make_sharded(model, params, **kw):
     kw.setdefault("page_size", PAGE)
     kw.setdefault("block_k", BLOCK_K)
     kw.setdefault("prefill_chunk", CHUNK)
+    kw.setdefault("interpret", True)
     return ShardedPagedServingSession(model, params, **kw)
 
 

@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import jax_compat
 from repro.roofline.hlo_cost import HloCostModel, cost_from_compiled, shape_bytes
 
 
@@ -45,7 +44,7 @@ def test_unrolled_matches_xla_cost_analysis():
     x = jax.ShapeDtypeStruct((32, 256), jnp.float32)
     compiled = _compiled(f, w, x)
     ours = cost_from_compiled(compiled)
-    xla = jax_compat.cost_analysis_dict(compiled)
+    xla = compiled.cost_analysis()
     assert ours.flops == pytest.approx(float(xla["flops"]), rel=0.05)
 
 

@@ -1,7 +1,7 @@
 """Property-style sweeps for core/numerics, hypothesis-free by construction.
 
-Complements tests/test_numerics.py (which uses hypothesis or its conftest
-shim): these are plain seeded ``pytest.mark.parametrize`` sweeps, so they run
+Complements tests/test_numerics.py (which uses hypothesis): these are
+plain seeded ``pytest.mark.parametrize`` sweeps, so they run
 identically everywhere and pin down the exact properties the AMLA kernels
 lean on — the compensated-increment identity, the MIN_EXP_DELTA clamp, and
 the zero-increment skip that ``rescale_skip_rate`` measures.

@@ -55,6 +55,9 @@ GEOMETRIES = [
     pytest.param(1, 4, 128, 128, 64, [60], id="short-single-page"),
     pytest.param(3, 8, 128, 64, 64, [200, 37, 130], id="ragged-batch"),
     pytest.param(1, 8, 576, 512, 128, [1210], id="paper-multi-page-long"),
+    # More query rows than one VMEM tile: the queue kernel runs 3 row
+    # tiles of 368 (4 pad rows), like a 32-token prefill chunk at 128 heads.
+    pytest.param(2, 1100, 128, 64, 64, [200, 37], id="row-tiled"),
 ]
 
 

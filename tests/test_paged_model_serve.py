@@ -39,6 +39,7 @@ def make_paged(model, params, **kw):
     kw.setdefault("page_size", PAGE)
     kw.setdefault("block_k", BLOCK_K)
     kw.setdefault("prefill_chunk", CHUNK)
+    kw.setdefault("interpret", True)
     return PagedServingSession(model, params, **kw)
 
 
@@ -176,7 +177,13 @@ def test_int8_cache_greedy_parity_and_bytes(model_and_params):
     b16 = sessions["bf16"].work_stats()
     i8 = sessions["int8"].work_stats()
     assert b16["page_dmas"] == i8["page_dmas"]  # same schedule, same DMAs
-    assert b16["page_dma_bytes"] / i8["page_dma_bytes"] >= 1.9
+    # Bytes the page DMAs move: 80-wide rows occupy one 128-lane tile, and
+    # an int8 page's scales ride in one lane-padded fp32 row.  At this
+    # 16-row page that row costs a fifth of the int8 data (the 576-wide
+    # serving geometry keeps the ~2x win, tests/test_quantized_cache.py).
+    assert b16["page_dma_bytes"] == b16["page_dmas"] * PAGE * 128 * 2
+    assert i8["page_dma_bytes"] == i8["page_dmas"] * (PAGE * 128 + 128 * 4)
+    assert b16["page_dma_bytes"] / i8["page_dma_bytes"] == 1.6
     assert sessions["int8"].cache.quantized
 
 

@@ -383,6 +383,7 @@ def make_paged(model, params, **kw):
     kw.setdefault("page_size", PAGE)
     kw.setdefault("block_k", BLOCK_K)
     kw.setdefault("prefill_chunk", CHUNK)
+    kw.setdefault("interpret", True)
     return PagedServingSession(model, params, **kw)
 
 

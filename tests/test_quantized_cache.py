@@ -48,9 +48,10 @@ def test_cache_spec_names_and_bytes():
     bf16 = CacheSpec.from_name("bf16")
     int8 = CacheSpec.from_name("int8")
     assert not bf16.quantized and int8.quantized
-    # the headline: int8 pages move ~half the bytes, scale strip included
-    assert bf16.bytes_per_page(128, 576) == 128 * 576 * 2
-    assert int8.bytes_per_page(128, 576) == 128 * (576 + 4)
+    # the headline: int8 pages move ~half the bytes, scale strip included;
+    # DMAs move whole 128-lane tiles, so a 576-wide row moves 640 lanes
+    assert bf16.bytes_per_page(128, 576) == 128 * 640 * 2
+    assert int8.bytes_per_page(128, 576) == 128 * 640 + 128 * 4
     ratio = bf16.bytes_per_page(128, 576) / int8.bytes_per_page(128, 576)
     assert ratio >= 1.9
     with pytest.raises(ValueError, match="unknown cache dtype"):

@@ -54,6 +54,7 @@ def make_sharded(model, params, **kw):
     kw.setdefault("page_size", PAGE)
     kw.setdefault("block_k", BLOCK_K)
     kw.setdefault("prefill_chunk", CHUNK)
+    kw.setdefault("interpret", True)
     return ShardedPagedServingSession(model, params, **kw)
 
 
@@ -62,6 +63,7 @@ def make_single(model, params, **kw):
     kw.setdefault("page_size", PAGE)
     kw.setdefault("block_k", BLOCK_K)
     kw.setdefault("prefill_chunk", CHUNK)
+    kw.setdefault("interpret", True)
     return PagedServingSession(model, params, **kw)
 
 
@@ -376,6 +378,7 @@ def test_serve_driver_mesh_backend():
     out = run_script(
         [
             "-m", "repro.launch.serve", "--cache", "paged", "--smoke",
+            "--interpret",
             "--mesh", "2x1", "--requests", "2", "--gen-len", "3",
             "--batch", "2",
         ]

@@ -339,6 +339,7 @@ def _mk_session(model, params, **kw):
     kw.setdefault("page_size", PAGE)
     kw.setdefault("block_k", BLOCK_K)
     kw.setdefault("prefill_chunk", CHUNK)
+    kw.setdefault("interpret", True)
     return PagedServingSession(model, params, **kw)
 
 
@@ -391,6 +392,7 @@ def test_speculative_sharded_matches_single_host(model_and_params):
     sharded = ShardedPagedServingSession(
         model, params, num_pages=64, shards=2, page_size=PAGE,
         block_k=BLOCK_K, prefill_chunk=CHUNK, speculate="ngram", draft_k=3,
+        interpret=True,
     )
     r_single = [single.add_request(p) for p in prompts]
     r_sharded = [sharded.add_request(p) for p in prompts]
