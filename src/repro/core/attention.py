@@ -158,7 +158,7 @@ def multi_head_attention(
 
 def mla_attention(
     q: jax.Array,  # (B, Sq, Hq, Dk)  absorbed queries (Dk = Dc + Dr = 576)
-    c_kv: jax.Array,  # (B, Sk, Dk)   shared latent cache (rope part included)
+    c_kv: jax.Array,  # (B, Sk, >=Dk) shared latent cache (rope part included)
     *,
     d_v: int = 512,  # latent value width (Dv = Dc)
     variant: str = "amla",
@@ -175,7 +175,8 @@ def mla_attention(
     Returns (B, Sq, Hq, d_v).
     """
     b, sq, hq, dk = q.shape
-    k = c_kv[:, :, None, :]  # Hkv = 1
+    # Cache rows may be lane-padded past Dk (init_latent_cache).
+    k = c_kv[:, :, None, :dk]  # Hkv = 1
     v = c_kv[:, :, None, :d_v]
     if scale is None:
         scale = 1.0 / (dk**0.5)

@@ -53,15 +53,21 @@ DEFAULT_BLOCK_K = 512
 # kernel's page size, so both kernels share the same pipeline shape.
 SUB_K = 128
 LANES = 128
-# Most query rows one kernel program holds in VMEM (q, output, lse and the
-# f32 accumulator); the queue and combine kernels run more as grid tiles.
-ROW_TILE = 512
+# The TPU compiler's default scoped-VMEM limit on v5e; programs that need
+# more ask for it through ``vmem_limit_bytes``, up to a core's whole VMEM.
+DEFAULT_SCOPED_VMEM = 16 << 20
+VMEM_BYTES = 128 << 20
 
 
 def lane_pad(n: int) -> int:
     """``n`` rounded up to whole 128-lane tiles: the minor-dim width a TPU
     array occupies in memory, and the only widths a DMA may slice."""
     return -(-n // LANES) * LANES
+
+
+# Most query rows one decode kernel update works on at a time; a program
+# with more loops over row tiles of at most this many rows.
+ROW_TILE = 512
 
 
 def row_tiling(g: int) -> tuple[int, int]:
@@ -73,6 +79,51 @@ def row_tiling(g: int) -> tuple[int, int]:
     if n == 1:
         return 1, g
     return n, -(-g // (16 * n)) * 16
+
+
+def for_each_row_tile(n_tiles: int, tile_g: int, fn, *, first: int = 0):
+    """Call ``fn(rows)`` for row tiles ``first .. n_tiles - 1``, ``rows`` a
+    ``pl.ds`` of ``tile_g`` rows.  Several tiles run as a loop, not
+    unrolled, so the program's code is one tile's whatever the row count."""
+    if first >= n_tiles:
+        return
+    if n_tiles - first == 1:
+        fn(pl.ds(first * tile_g, tile_g))
+        return
+
+    def body(r, carry):
+        fn(pl.ds(pl.multiple_of(r * tile_g, tile_g), tile_g))
+        return carry
+
+    jax.lax.fori_loop(first, n_tiles, body, 0)
+
+
+def vmem_limit_bytes(
+    g, tile_g, *, d_k, d_v, block_k, q_dtype, kv_dtype
+) -> int | None:
+    """Scoped-VMEM limit for a decode program that holds ``g`` query rows
+    and updates ``tile_g`` of them at a time, or None when the compiler's
+    default suffices.
+
+    Per held row: the double-buffered q, position, output and lse blocks (a
+    ``(g, 1)`` block occupies a whole 128-lane tile), the f32 accumulator
+    and five state columns.  Per tile row: the f32 score/probability strips
+    of one KV block.  The staged KV block and 4 MiB of compiler scratch come
+    on top, and a quarter for what the count misses.  For 4096 rows in 8
+    tiles of 512 (a 32-token prefill chunk at 128 heads) it asks for 76 MiB;
+    the v5e compiler needs between 54 and 58 MiB.
+    """
+    q_b = jnp.dtype(q_dtype).itemsize
+    col = LANES * 4  # one (rows, 1) f32/i32 column, lane-padded
+    per_row = (
+        2 * d_k * q_b + 2 * col  # q, q_pos blocks
+        + 2 * d_v * 4 + 2 * col  # output, lse blocks
+        + d_v * 4 + 5 * col  # acc, m/l/n/gamma/s16
+    )
+    per_tile_row = block_k * (3 * 4 + q_b)  # scores, p, scaled p; MXU p
+    fixed = 2 * block_k * d_k * jnp.dtype(kv_dtype).itemsize + (4 << 20)
+    need = (g * per_row + tile_g * per_tile_row + fixed) * 5 // 4
+    return None if need <= DEFAULT_SCOPED_VMEM else need
 
 
 def decode_state_scratch(g: int, d_v: int) -> list:
@@ -186,28 +237,44 @@ def preload_block_scores(
         if j + 1 < n_sub:
             issue(j + 1)
         wait(j)
-        strip = kv_view[pl.ds(j * sub_k, sub_k), :]
-        if strip.dtype != q_ref.dtype:
-            # int8 pages (and bf16 pages under fp32 compute) are cast
-            # per-strip here, inside the pipeline — never a whole pool.
-            strip = strip.astype(q_ref.dtype)
-        s_j = jax.lax.dot_general(
-            q_ref[...],
-            strip,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if scale_view is not None:
-            # Per-key-row dequant: score column j scales by its row's σ.
-            s_j = s_j * _scale_strip(scale_view, j, sub_k)
-        parts.append(s_j)
+        parts.append(_strip_scores(q_ref, kv_view, scale_view, j, sub_k))
     return jnp.concatenate(parts, axis=1) if n_sub > 1 else parts[0]
+
+
+def staged_block_scores(q_ref, kv_view, *, n_sub, sub_k, scale_view=None):
+    """The (G, n_sub*sub_k) FP32 scores of ``q_ref`` against a block that
+    :func:`preload_block_scores` has already staged (and zero-filled past
+    ``kv_len``): further row tiles of the same work item reuse the staged
+    pages instead of DMA-ing them again."""
+    parts = [
+        _strip_scores(q_ref, kv_view, scale_view, j, sub_k)
+        for j in range(n_sub)
+    ]
+    return jnp.concatenate(parts, axis=1) if n_sub > 1 else parts[0]
+
+
+def _strip_scores(q_ref, kv_view, scale_view, j, sub_k):
+    """Scores of ``q_ref`` against staged sub-tile ``j``."""
+    strip = kv_view[pl.ds(j * sub_k, sub_k), :]
+    if strip.dtype != q_ref.dtype:
+        # int8 pages (and bf16 pages under fp32 compute) are cast per strip
+        # here, inside the pipeline — never a whole pool.
+        strip = strip.astype(q_ref.dtype)
+    s_j = jax.lax.dot_general(
+        q_ref[...],
+        strip,
+        (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    if scale_view is not None:
+        # Per-key-row dequant: score column j scales by its row's σ.
+        s_j = s_j * _scale_strip(scale_view, j, sub_k)
+    return s_j
 
 
 def _scale_strip(scale_view, j, sub_k):
     """Sub-tile ``j``'s ``(1, sub_k)`` scales out of its staged row."""
-    row = scale_view[pl.ds(j, 1), :]
-    return row if row.shape[1] == sub_k else row[:, :sub_k]
+    return scale_view[pl.ds(j, 1), :][:, :sub_k]
 
 
 def block_scale_row(scale_view, *, n_sub, sub_k):
@@ -393,9 +460,10 @@ def _mla_decode_kernel(
         # Cross-step lookahead: start the next block's first sub-tile now so
         # its copy overlaps this block's state update.  No next-block check
         # against the grid bound is needed: start + block_k < k_len already
-        # implies another block of this request exists (the cache is padded
-        # to a block multiple), and a false condition at the row's last live
-        # block also stops the chain before the next request row.
+        # implies another block of this request exists (and the cache rows
+        # are a whole number of sub-tiles), and a false condition at the
+        # row's last live block also stops the chain before the next request
+        # row.
         prefetch_next_first_subtile(
             lambda: c_hbm.at[b, pl.ds(start + block_k, sub_k), :],
             kv_blk_ref.at[1 - cur],
@@ -447,7 +515,10 @@ def mla_decode_rows(
     softcap: float | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Row-level entry point; see ops.mla_decode for the (B,Sq,H,D) API."""
+    """Row-level entry point; see ops.mla_decode for the (B,Sq,H,D) API.
+
+    ``c_kv`` rows may be lane-padded past ``Dk`` (``init_latent_cache``
+    allocates them so); ``q`` is zero-padded to match."""
     b, g, d_k = q.shape
     s = c_kv.shape[1]
     # Clamp to the cache length, staying a multiple of the sub-tile size so
@@ -456,17 +527,18 @@ def mla_decode_rows(
     block_k = min(block_k, -(-max(s, 1) // sub_k) * sub_k)
     if block_k % sub_k:
         raise ValueError(f"block_k={block_k} must be a multiple of {sub_k}")
-    # Rows pad to a block multiple and to whole 128-lane tiles: a sub-tile
-    # DMA may not slice 576 of a row's 640 lanes.  Zero lanes in q and the
-    # cache add nothing to the scores.
-    pad = (-s) % block_k
-    lanes = lane_pad(d_k) - d_k
-    if pad or lanes:
-        c_kv = jnp.pad(c_kv, ((0, 0), (0, pad), (0, lanes)))
-    if lanes:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, lanes)))
-        d_k += lanes
-    n_blocks = c_kv.shape[1] // block_k
+    # A sub-tile DMA may only move whole sub-tiles of rows and whole
+    # 128-lane tiles of each row (not 576 of a row's 640 lanes), so a cache
+    # that is not already aligned is copied once here.  Zero lanes in q and
+    # the cache add nothing to the scores.
+    rows = (-s) % sub_k
+    lanes = lane_pad(d_k) - c_kv.shape[-1]
+    if rows or lanes:
+        c_kv = jnp.pad(c_kv, ((0, 0), (0, rows), (0, lanes)))
+    if c_kv.shape[-1] != d_k:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, c_kv.shape[-1] - d_k)))
+        d_k = c_kv.shape[-1]
+    n_blocks = -(-c_kv.shape[1] // block_k)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -502,6 +574,10 @@ def mla_decode_rows(
         out_shape=jax.ShapeDtypeStruct((b, g, d_v), jnp.float32),
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes(
+                g, g, d_k=d_k, d_v=d_v, block_k=block_k,
+                q_dtype=q.dtype, kv_dtype=c_kv.dtype,
+            ),
         ),
         interpret=interpret,
     )(kv_len.astype(jnp.int32), q, q_pos.astype(jnp.int32)[..., None], c_kv)

@@ -54,13 +54,13 @@ rescale-skip statistics at the paper's block granularity while the DMA
 granularity stays one page.
 
 **Quantized pools** (:class:`CacheSpec` with ``dtype=int8``): the pool
-stores symmetric per-row int8 latents and a companion ``(P, page_size)``
-fp32 scale pool.  The queue kernel stages each page's int8 strip *and* its
-scale strip through the same double-buffered ``make_async_copy`` pipeline
-(scale strips on their own semaphores, prefetched cross-step alongside the
-data), multiplies the scales into the per-page score strip right after the
-MXU matmul and folds them into the probability rows before the PV
-accumulate — so dequantization costs two VPU multiplies inside the
+stores symmetric per-row int8 latents and a companion fp32 scale pool
+with one lane-padded row of scales per page.  The queue kernel stages each
+page's int8 strip *and* its scale strip through the same double-buffered
+``make_async_copy`` pipeline (scale strips on their own semaphores,
+prefetched cross-step alongside the data), multiplies the scales into the
+per-page score strip right after the MXU matmul and folds them into the
+probability rows before the PV accumulate — so dequantization costs two VPU multiplies inside the
 DMA-overlap window, page DMAs move ~half the bytes, and the AMLA
 MUL-by-ADD state machine sees the same fp32 state as a bf16 pool.  The
 padded baseline grid has no dequant path and stays bf16-only.
@@ -257,7 +257,7 @@ def _mla_decode_paged_kernel(
 )
 def mla_decode_paged_rows(
     q: jax.Array,  # (B, G, Dk)
-    kv_pages: jax.Array,  # (P, page_size, Dk) physical page pool
+    kv_pages: jax.Array,  # (P, page_size, Dk) page pool, Dk lane-padded
     block_tables: jax.Array,  # (B, W) int32
     kv_len: jax.Array,  # (B,) int32
     q_pos: jax.Array,  # (B, G) int32
@@ -347,8 +347,8 @@ def _mla_decode_queue_kernel(
     # inputs: q_ref, q_pos_ref, pages_hbm[, scales_hbm], then outputs and
     # scratch — the quantized variant splices the scale pool input and its
     # staging scratch in, so the tail is unpacked by arity below.
-    q_ref,  # (Gt, Dk) bf16 row tile (block selected by item_req)
-    q_pos_ref,  # (Gt, 1) int32 absolute position per query row (VMEM)
+    q_ref,  # (G, Dk) bf16 (block selected by item_req)
+    q_pos_ref,  # (G, 1) int32 absolute position per query row (VMEM)
     pages_hbm,  # (P, page_size, Dk) page pool, resident in HBM (ANY)
     *rest,
     scale: float,
@@ -358,6 +358,7 @@ def _mla_decode_queue_kernel(
     block_k: int,
     softcap: float | None,
     quantized: bool,
+    tile_g: int,
 ):
     if quantized:
         # scales_hbm: (P, SW) f32 per-row dequant scales, lane-padded (ANY);
@@ -371,21 +372,28 @@ def _mla_decode_queue_kernel(
          s16_ref, kv_blk_ref, sem) = rest
         scales_hbm = scale_blk_ref = scale_sem = None
 
-    # Grid is (row tile, work item): each row tile walks the whole queue.
-    t = pl.program_id(1)
-    n_items = pl.num_programs(1)
+    t = pl.program_id(0)
+    n_items = pl.num_programs(0)
     req = ireq_ref[t]
     blk = iblk_ref[t]
     first = ifst_ref[t]
     last = ilst_ref[t]
     valid = ivld_ref[t]
+    n_tiles = q_ref.shape[0] // tile_g
+    state = (acc_ref, m_ref, l_ref, n_ref, gamma_ref, s16_ref)
+
+    def tile_state(rows):
+        return [ref.at[rows] for ref in state]
 
     # A dest slot's items are contiguous in the queue, so its online-softmax
     # state lives in scratch across grid steps: init on the first item,
     # finalize+write on the last.  Padding items re-init harmlessly.
     @pl.when(first == 1)
     def _init():
-        _mla.init_decode_state(acc_ref, m_ref, l_ref, n_ref, gamma_ref, s16_ref)
+        _mla.for_each_row_tile(
+            n_tiles, tile_g,
+            lambda rows: _mla.init_decode_state(*tile_state(rows)),
+        )
 
     k_len = kv_len_ref[req]
     start = blk * block_k
@@ -415,8 +423,25 @@ def _mla_decode_queue_kernel(
         def scale_src(j):
             return scales_hbm.at[pl.ds(bt_ref[req, first_page + j], 1), :]
 
+        def update(rows, s):
+            s = s * jnp.float32(scale)
+            if softcap is not None:
+                s = numerics.softcap(s, softcap)
+            s = jnp.clip(s, -numerics.M_CLAMP, numerics.M_CLAMP)
+            k_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = (k_pos < k_len) & (k_pos <= q_pos_ref[rows, :])
+            s = jnp.where(mask, s, -jnp.inf)
+            _mla.decode_block_update(
+                s, kv_view[...], *tile_state(rows),
+                d_v=d_v, variant=variant, mm_dtype=q_ref.dtype,
+                kv_scale=None if scale_view is None else _mla.block_scale_row(
+                    scale_view, n_sub=n_sub, sub_k=page_size
+                ),
+            )
+
+        rows0 = pl.ds(0, tile_g)
         s = _mla.preload_block_scores(
-            q_ref, kv_view, n_sub=n_sub, sub_k=page_size,
+            q_ref.at[rows0], kv_view, n_sub=n_sub, sub_k=page_size,
             src=src, live=live, sem=sem, first_prefetched=t > 0,
             scale_view=scale_view,
             scale_src=scale_src if quantized else None,
@@ -443,35 +468,33 @@ def _mla_decode_queue_kernel(
             ),
             scale_sem=scale_sem,
         )
-        s = s * jnp.float32(scale)
-        if softcap is not None:
-            s = numerics.softcap(s, softcap)
-        s = jnp.clip(s, -numerics.M_CLAMP, numerics.M_CLAMP)
-
-        k_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = (k_pos < k_len) & (k_pos <= q_pos_ref[...])
-        s = jnp.where(mask, s, -jnp.inf)
-
-        _mla.decode_block_update(
-            s, kv_view[...],
-            acc_ref, m_ref, l_ref, n_ref, gamma_ref, s16_ref,
-            d_v=d_v, variant=variant, mm_dtype=q_ref.dtype,
-            kv_scale=None if scale_view is None else _mla.block_scale_row(
-                scale_view, n_sub=n_sub, sub_k=page_size
-            ),
+        update(rows0, s)
+        # Further row tiles score against the block already staged: each
+        # page is DMA'd once per work item, however many rows attend it.
+        _mla.for_each_row_tile(
+            n_tiles, tile_g,
+            lambda rows: update(rows, _mla.staged_block_scores(
+                q_ref.at[rows], kv_view, n_sub=n_sub, sub_k=page_size,
+                scale_view=scale_view,
+            )),
+            first=1,
         )
 
     @pl.when((last == 1) & (valid == 1))
     def _finalize():
-        _mla.finalize_decode(o_ref, acc_ref, l_ref, s16_ref, variant=variant)
-        # lse in *standard* units (m is the true running max, l the plain
-        # softmax mass in both variants) so split partials combine
-        # variant-agnostically.
-        l = l_ref[...]
-        safe = jnp.where(l > 0, l, 1.0)
-        lse_ref[...] = jnp.where(
-            l > 0, m_ref[...] + jnp.log(safe), -jnp.inf
-        )
+        def fin(rows):
+            acc, m, l_st, _, _, s16 = tile_state(rows)
+            _mla.finalize_decode(o_ref.at[rows], acc, l_st, s16, variant=variant)
+            # lse in *standard* units (m is the true running max, l the
+            # plain softmax mass in both variants) so split partials combine
+            # variant-agnostically.
+            l = l_st[...]
+            safe = jnp.where(l > 0, l, 1.0)
+            lse_ref[rows, :] = jnp.where(
+                l > 0, m[...] + jnp.log(safe), -jnp.inf
+            )
+
+        _mla.for_each_row_tile(n_tiles, tile_g, fin)
 
 
 @functools.partial(
@@ -488,7 +511,7 @@ def _mla_decode_queue_kernel(
 )
 def mla_decode_paged_queue_rows(
     q: jax.Array,  # (B, G, Dk)
-    kv_pages: jax.Array,  # (P, page_size, Dk) physical page pool
+    kv_pages: jax.Array,  # (P, page_size, Dk) page pool, Dk lane-padded
     block_tables: jax.Array,  # (B, W) int32
     kv_len: jax.Array,  # (B,) int32
     q_pos: jax.Array,  # (B, G) int32
@@ -498,7 +521,7 @@ def mla_decode_paged_queue_rows(
     item_first: jax.Array,  # (N,) int32 │ (see decode_schedule)
     item_last: jax.Array,  # (N,) int32 │
     item_valid: jax.Array,  # (N,) int32 ┘
-    kv_scales: jax.Array | None = None,  # (P, page_size) f32, int8 pools
+    kv_scales: jax.Array | None = None,  # (P, SW) f32, int8 pools
     *,
     d_v: int = 512,
     variant: str = "amla",
@@ -538,9 +561,10 @@ def mla_decode_paged_queue_rows(
         block_tables, kv_len, page_size, num_pages
     )
     n_items = item_req.shape[0]
-    # Query rows run in tiles of at most ROW_TILE (a 32-token prefill chunk
-    # at 128 heads is 4096 rows); padded rows carry q_pos = -1, which masks
-    # every key, and are sliced off below.
+    # A program holds all of a request's query rows (a 32-token prefill
+    # chunk at 128 heads is 4096) and updates them in row tiles, so each
+    # page is DMA'd once per work item.  Pad rows carry q_pos = -1, which
+    # masks every key, and are sliced off below.
     n_tiles, tile_g = _mla.row_tiling(g)
     g_pad = n_tiles * tile_g
     q_pos = q_pos.astype(jnp.int32)
@@ -548,11 +572,11 @@ def mla_decode_paged_queue_rows(
         q = jnp.pad(q, ((0, 0), (0, g_pad - g), (0, 0)))
         q_pos = jnp.pad(q_pos, ((0, 0), (0, g_pad - g)), constant_values=-1)
 
-    # index_maps see (row tile, item, *scalar refs); refs[2] is item_req and
-    # refs[4] item_dest.
+    # index_maps see (item, *scalar refs); refs[2] is item_req and refs[4]
+    # item_dest.
     data_specs = [
-        pl.BlockSpec((None, tile_g, d_k), lambda r, t, *refs: (refs[2][t], r, 0)),
-        pl.BlockSpec((None, tile_g, 1), lambda r, t, *refs: (refs[2][t], r, 0)),
+        pl.BlockSpec((None, g_pad, d_k), lambda t, *refs: (refs[2][t], 0, 0)),
+        pl.BlockSpec((None, g_pad, 1), lambda t, *refs: (refs[2][t], 0, 0)),
         pl.BlockSpec(memory_space=ANY),
     ]
     extra_scratch = []
@@ -568,20 +592,17 @@ def mla_decode_paged_queue_rows(
         ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=8,
-        # Flat queue per row tile; scratch-carried state makes the queue
-        # axis sequential ("arbitrary"), which is what lets one dest span
-        # several items.  Row tiles are independent.
-        grid=(n_tiles, n_items),
+        # Flat queue; scratch-carried state makes it sequential
+        # ("arbitrary"), which is what lets one dest span several items.
+        grid=(n_items,),
         in_specs=data_specs,
         out_specs=[
             pl.BlockSpec(
-                (None, tile_g, d_v), lambda r, t, *refs: (refs[4][t], r, 0)
+                (None, g_pad, d_v), lambda t, *refs: (refs[4][t], 0, 0)
             ),
-            pl.BlockSpec(
-                (None, tile_g, 1), lambda r, t, *refs: (refs[4][t], r, 0)
-            ),
+            pl.BlockSpec((None, g_pad, 1), lambda t, *refs: (refs[4][t], 0, 0)),
         ],
-        scratch_shapes=_mla.decode_state_scratch(tile_g, d_v)
+        scratch_shapes=_mla.decode_state_scratch(g_pad, d_v)
         + [
             # Double-buffered so the cross-step lookahead can stage the next
             # item's first page while this item is still being read.
@@ -599,7 +620,18 @@ def mla_decode_paged_queue_rows(
         block_k=block_k,
         softcap=softcap,
         quantized=quantized,
+        tile_g=tile_g,
     )
+    vmem_limit = _mla.vmem_limit_bytes(
+        g_pad, tile_g, d_k=d_k, d_v=d_v, block_k=block_k,
+        q_dtype=q.dtype, kv_dtype=kv_pages.dtype,
+    )
+    if not interpret and vmem_limit is not None and vmem_limit > _mla.VMEM_BYTES:
+        raise ValueError(
+            f"{g} query rows per request need about {vmem_limit >> 20} MiB "
+            f"of VMEM, more than a core has ({_mla.VMEM_BYTES >> 20} MiB); "
+            f"use a smaller prefill chunk, draft_k or prefix group"
+        )
     inputs = [q, q_pos[..., None], kv_pages]
     if quantized:
         inputs.append(kv_scales.astype(jnp.float32))
@@ -611,7 +643,8 @@ def mla_decode_paged_queue_rows(
             jax.ShapeDtypeStruct((num_dest_slots, g_pad, 1), jnp.float32),
         ],
         compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit,
         ),
         interpret=interpret,
     )(
@@ -635,7 +668,7 @@ def mla_decode_paged_queue_rows(
 
 def mla_decode_paged_group_prefix(
     q: jax.Array,  # (B, G, Dk) per-request query rows
-    kv_pages: jax.Array,  # (P, page_size, Dk) physical page pool
+    kv_pages: jax.Array,  # (P, page_size, Dk) page pool, Dk lane-padded
     block_tables: jax.Array,  # (B, W) int32
     q_pos: jax.Array,  # (B, G) int32 real query positions
     group_member: jax.Array,  # (n_groups, gmax) int32 request idx, -1 pad
@@ -647,7 +680,7 @@ def mla_decode_paged_group_prefix(
     item_first: jax.Array,  # │ (group, shared kv_block)
     item_last: jax.Array,  # │
     item_valid: jax.Array,  # ┘
-    kv_scales: jax.Array | None = None,  # (P, page_size) f32, int8 pools
+    kv_scales: jax.Array | None = None,  # (P, SW) f32, int8 pools
     *,
     d_v: int = 512,
     variant: str = "amla",

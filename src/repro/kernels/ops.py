@@ -29,7 +29,7 @@ def _default_pos(b, sq, kv_len, sk):
 
 def mla_decode(
     q: jax.Array,  # (B, Sq, Hq, Dk)
-    c_kv: jax.Array,  # (B, S, Dk)
+    c_kv: jax.Array,  # (B, S, >=Dk), rows lane-padded by init_latent_cache
     *,
     d_v: int = 512,
     variant: str = "amla",
@@ -97,12 +97,12 @@ def _validate_paged_geometry(
                 f"kv_scales given but kv_pages dtype is {kv_pages.dtype} — "
                 f"scale pools accompany int8 pools only"
             )
-        lanes = _mla.lane_pad(page_size)
-        if kv_scales.shape not in ((num_pages, page_size), (num_pages, lanes)):
+        want = (num_pages, _mla.lane_pad(page_size))
+        if tuple(kv_scales.shape) != want:
             raise ValueError(
-                f"kv_scales must be (num_pages={num_pages}, "
-                f"page_size={page_size}) — one fp32 scale per page row, "
-                f"optionally lane-padded to {lanes}; got {kv_scales.shape}"
+                f"kv_scales must be {want} — one fp32 scale per page row, "
+                f"each page's row lane-padded (kv_cache.scale_pool_shape); "
+                f"got {kv_scales.shape}"
             )
     if block_tables.ndim != 2 or block_tables.shape[0] != b:
         raise ValueError(
@@ -116,11 +116,12 @@ def _validate_paged_geometry(
             "use PagedKVCache.block_table, which pads empty sequences to "
             "width 1"
         )
-    if dk_pages not in (q.shape[-1], _mla.lane_pad(q.shape[-1])):
+    if dk_pages != _mla.lane_pad(q.shape[-1]):
         raise ValueError(
-            f"q feature width {q.shape[-1]} != page row width {dk_pages}; "
-            f"queries and the latent page pool must share D_k (the pool "
-            f"may pad it to whole 128-lane tiles)"
+            f"queries and the page pool must share D_k: page rows are "
+            f"{dk_pages} wide, but q's D_k={q.shape[-1]} needs pool rows of "
+            f"{_mla.lane_pad(q.shape[-1])} (D_k padded to whole 128-lane "
+            f"tiles, kv_cache.pool_shape)"
         )
     if block_k is not None and (block_k < page_size or block_k % page_size):
         raise ValueError(
@@ -201,11 +202,11 @@ def _validate_q_positions(q_positions, b, sq, kv_len, scheduler, q_offset, causa
 
 def mla_decode_paged(
     q: jax.Array,  # (B, Sq, Hq, Dk)
-    kv_pages: jax.Array,  # (P, page_size, Dk) physical page pool
+    kv_pages: jax.Array,  # kv_cache.pool_shape(P, page_size, Dk) page pool
     block_tables: jax.Array,  # (B, W) int32 logical -> physical page ids
     kv_len: jax.Array,  # (B,) int32 valid tokens per request
     *,
-    kv_scales: jax.Array | None = None,  # (P, page_size) f32 (int8 pools)
+    kv_scales: jax.Array | None = None,  # scale_pool_shape f32 (int8 pools)
     d_v: int = 512,
     variant: str = "amla",
     interpret: bool = False,
@@ -305,9 +306,8 @@ def mla_decode_paged(
         q_pos = jnp.full((b, sq), cap, jnp.int32)  # no causal restriction
     rows_pos = jnp.repeat(q_pos, hq, axis=1)  # (B, Sq*Hq)
     q_rows = q.reshape(b, sq * hq, dk).astype(compute_dtype)
-    if kv_pages.shape[-1] != dk:
-        # Lane-padded pool rows: zero query lanes add nothing to the scores.
-        q_rows = jnp.pad(q_rows, ((0, 0), (0, 0), (0, kv_pages.shape[-1] - dk)))
+    # Pool rows are lane-padded; zero query lanes add nothing to the scores.
+    q_rows = jnp.pad(q_rows, ((0, 0), (0, 0), (0, kv_pages.shape[-1] - dk)))
 
     if return_partials and (scheduler != "queue" or prefix_sharing):
         raise ValueError(

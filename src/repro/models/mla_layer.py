@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.attention import mla_attention
+from repro.kernels.mla_decode import lane_pad
 from repro.models import layers
 
 
@@ -88,8 +89,15 @@ def _mla_apply_expanded(
 
 
 def init_latent_cache(cfg, batch, max_len, dtype=jnp.bfloat16):
+    """Dense latent cache ``{"c": (batch, max_len, W)}``.
+
+    ``W`` is the 576-wide latent row padded to whole 128-lane tiles (640),
+    as the paged pool pads it: the decode kernel's DMAs move whole tiles,
+    and a TPU lays a 576-wide array out in 640 lanes anyway.  Writes fill
+    the first 576 lanes; the pad lanes stay zero."""
     m = cfg.mla
-    return {"c": jnp.zeros((batch, max_len, m.d_latent + m.d_rope), dtype)}
+    width = lane_pad(m.d_latent + m.d_rope)
+    return {"c": jnp.zeros((batch, max_len, width), dtype)}
 
 
 def mla_scale(cfg) -> float:

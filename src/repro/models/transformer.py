@@ -320,11 +320,6 @@ def check_paged_compatible(cfg) -> None:
         )
 
 
-# Batch rows of one TPU sublane tile: the MXU pads a matmul's rows to it
-# anyway, so a decode step padded to a multiple costs no extra compute.
-DECODE_ROW_TILE = 8
-
-
 def per_layer_params(params, cfg) -> list[dict]:
     """Unstack scanned group params into an L-element per-layer list.
 
@@ -554,6 +549,7 @@ def lm_decode_step_paged(
     layer_params: list | None = None,
     compute_dtype=None,
     head_shards: int = 1,
+    batch_rows: int | None = None,
 ) -> jax.Array:
     """One paged full-model decode step; returns logits ``(B, S, vocab)``.
 
@@ -573,6 +569,15 @@ def lm_decode_step_paged(
     row j's logits are valid whenever drafts 1..j matched greedy; the
     caller accepts the longest matching prefix and rolls the cache back
     with ``LayeredPagedKVCache.truncate``.
+
+    ``batch_rows`` fixes the batch the dense per-layer programs run on: the
+    B live rows are padded to a multiple of it (pad rows are token 0 at
+    position 0; only the kernel and the cache writes see the live rows).
+    XLA compiles and fuses a program differently per batch size, which
+    moves bf16 roundings, so a request's logits are independent of how many
+    others share its step only while the padded batch stays the same: up to
+    ``batch_rows`` live requests, every step and every session with the same
+    ``batch_rows`` runs one program.  None runs the live batch as it is.
     """
     from repro.kernels import decode_schedule as _sched
     from repro.kernels import ops
@@ -641,15 +646,8 @@ def lm_decode_step_paged(
         )
 
     bt, kv_len = jnp.asarray(bt), jnp.asarray(kv_len)
-    # The dense per-layer programs run on whole tiles of DECODE_ROW_TILE
-    # batch rows (pad rows are token 0 at position 0; only the kernel and
-    # the cache writes see the B live rows).  XLA fuses a program
-    # differently per batch size, which moves bf16 roundings; padding makes
-    # a request's logits independent of how many others share its step up
-    # to a tile, so a data shard serving 2 requests matches one chip
-    # serving 8 token for token.
     b = len(rids)
-    b_pad = -(-b // DECODE_ROW_TILE) * DECODE_ROW_TILE
+    b_pad = b if batch_rows is None else -(-b // batch_rows) * batch_rows
     tok = np.zeros((b_pad, s), np.int32)
     tok[:b] = tokens
     pos = np.zeros((b_pad, s), np.int32)

@@ -48,6 +48,8 @@ __all__ = [
     "OutOfPagesError",
     "PagedKVCache",
     "PrefixTrie",
+    "pool_shape",
+    "scale_pool_shape",
 ]
 
 
@@ -203,6 +205,24 @@ class OutOfPagesError(RuntimeError):
     """Raised when an append needs more pages than the pool has free."""
 
 
+def pool_shape(num_pages: int, page_size: int, width: int) -> tuple:
+    """Shape of a device page pool of ``width``-wide rows.
+
+    Rows are padded to whole 128-lane tiles (576 -> 640 for MLA): the
+    kernels' page DMAs may only move whole tiles, and a TPU lays a 576-wide
+    array out in 640 lanes anyway.  Pad lanes stay zero; writes and reads
+    touch the first ``width``.  Every pool the kernels read is built to this
+    shape, by :class:`PagedKVCache` and by tests that fill pools by hand."""
+    return (num_pages, page_size, lane_pad(width))
+
+
+def scale_pool_shape(num_pages: int, page_size: int) -> tuple:
+    """Shape of an int8 pool's fp32 scale pool: one row per page, its
+    first ``page_size`` lanes live, padded to whole 128-lane tiles so one
+    page's scales are one aligned DMA."""
+    return (num_pages, lane_pad(page_size))
+
+
 class DoubleFreeError(RuntimeError):
     """Raised (debug mode only) when a request id is freed twice."""
 
@@ -214,12 +234,8 @@ class PagedKVCache:
     ----------
     num_pages:  total pages in the device pool.
     page_size:  latent rows per page.
-    width:      row width (576 = 512 latent + 64 rope for DeepSeek MLA).
-                The device pool pads rows to whole 128-lane tiles
-                (:attr:`pool_width`, 640 for MLA) and the scale pool pads
-                each page's scales likewise (:attr:`scale_width`): the
-                kernels' page DMAs may only move whole tiles.  Pad lanes
-                stay zero; writes and reads touch the first ``width``.
+    width:      row width (576 = 512 latent + 64 rope for DeepSeek MLA);
+                the device pool pads it (:func:`pool_shape`).
     dtype:      storage dtype of the pool (bf16 in serving).  ``jnp.int8``
                 selects the quantized mode: rows are symmetric-quantized
                 on write against a companion per-row fp32 scale pool
@@ -248,8 +264,6 @@ class PagedKVCache:
         self.num_pages = num_pages
         self.page_size = page_size
         self.width = width
-        self.pool_width = lane_pad(width)
-        self.scale_width = lane_pad(page_size)
         self.spec = spec if spec is not None else CacheSpec(dtype=dtype)
         self.dtype = self.spec.dtype
         self.debug = debug
@@ -570,12 +584,14 @@ class PagedKVCache:
     def _make_pool(self) -> jax.Array:
         """Allocate the device page pool (layered subclasses override)."""
         return jnp.zeros(
-            (self.num_pages, self.page_size, self.pool_width), self.dtype
+            pool_shape(self.num_pages, self.page_size, self.width), self.dtype
         )
 
     def _make_scale_pool(self) -> jax.Array:
         """Per-page-row fp32 dequant scales (quantized pools only)."""
-        return jnp.zeros((self.num_pages, self.scale_width), jnp.float32)
+        return jnp.zeros(
+            scale_pool_shape(self.num_pages, self.page_size), jnp.float32
+        )
 
     def _pool_copy_page(self, dst_pid: int, src_pid: int) -> None:
         """Device-side page copy (the COW fault path)."""
@@ -765,7 +781,8 @@ class LayeredPagedKVCache(PagedKVCache):
     # -- pool hooks (layer-axis aware) --------------------------------- #
     def _make_pool(self) -> jax.Array:
         return jnp.zeros(
-            (self.num_layers, self.num_pages, self.page_size, self.pool_width),
+            (self.num_layers,)
+            + pool_shape(self.num_pages, self.page_size, self.width),
             self.dtype,
         )
 
@@ -773,7 +790,8 @@ class LayeredPagedKVCache(PagedKVCache):
         # Scales carry the layer axis too: every layer's latent rows are
         # quantized independently against their own row maxima.
         return jnp.zeros(
-            (self.num_layers, self.num_pages, self.scale_width), jnp.float32
+            (self.num_layers,) + scale_pool_shape(self.num_pages, self.page_size),
+            jnp.float32,
         )
 
     def _pool_copy_page(self, dst_pid: int, src_pid: int) -> None:
@@ -871,11 +889,11 @@ class LayeredPagedKVCache(PagedKVCache):
             )
 
     def layer_pages(self, layer: int) -> jax.Array:
-        """The ``(num_pages, page_size, pool_width)`` pool of one layer."""
+        """The :func:`pool_shape` pool of one layer."""
         return self.pages[layer]
 
     def layer_scales(self, layer: int) -> jax.Array | None:
-        """One layer's ``(num_pages, scale_width)`` scale pool (None unless
+        """One layer's :func:`scale_pool_shape` scale pool (None unless
         quantized) — passed to the kernels alongside :meth:`layer_pages`."""
         return None if self.scales is None else self.scales[layer]
 

@@ -322,6 +322,14 @@ class PagedServingSession:
       decode.  Slice boundaries land exactly on the chunk boundaries a
       monolithic prefill would use, so cache rows and greedy outputs stay
       bit-identical to phase-separated (``prefill_budget=None``) serving.
+    * ``max_batch`` caps admission and fixes the decode step's batch: the
+      dense programs always run on ``max_batch`` rows (``batch_rows`` of
+      ``lm_decode_step_paged``), so a request's greedy tokens do not depend
+      on how many others are live, and sessions with the same
+      ``max_batch`` (one chip, or one data shard of
+      :class:`ShardedPagedServingSession`) agree in bf16.  A resumed request
+      may push the live count past ``max_batch``; that step runs a program
+      of twice the rows, and bf16 parity is no longer guaranteed for it.
     """
 
     def __init__(
@@ -940,6 +948,8 @@ class PagedServingSession:
             layer_params=self._layers,
             compute_dtype=self.compute_dtype,
             head_shards=self.head_shards,
+            # One decode program per session, whatever the live count.
+            batch_rows=self.max_batch,
         )
         greedy = np.asarray(jnp.argmax(logits, axis=-1), np.int32)  # (B, s)
         self.work_units += 1  # one fused decode launch
@@ -1416,6 +1426,10 @@ class ShardedPagedServingSession:
                 prefix_sharing=prefix_sharing,
                 min_group=min_group,
                 prefill_chunk=prefill_chunk,
+                # A shard never holds more than the global batch; with the
+                # same max_batch its decode step runs the program one
+                # unsharded session runs, so greedy tokens match.
+                max_batch=max_batch,
                 interpret=interpret,
                 dtype=dtype,
                 kv_dtype=kv_dtype,
@@ -1461,6 +1475,7 @@ class ShardedPagedServingSession:
             prefix_sharing=prefix_sharing,
             min_group=min_group,
             prefill_chunk=prefill_chunk,
+            max_batch=max_batch,
             interpret=interpret,
             dtype=dtype,
             kv_dtype=kv_dtype,

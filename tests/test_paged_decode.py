@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.runtime.kv_cache import OutOfPagesError, PagedKVCache
+from repro.runtime.kv_cache import OutOfPagesError, PagedKVCache, pool_shape
 from repro.runtime.serve_loop import PagedDecodeSession
 
 INTERP = dict(interpret=True)
@@ -33,7 +33,7 @@ def paginate(c, kv_lens, page, *, num_pages, shuffle_seed=None):
     """
     b, s, dk = c.shape
     w = max(-(-int(l) // page) for l in kv_lens)
-    pool = np.zeros((num_pages, page, dk), np.float32)
+    pool = np.zeros(pool_shape(num_pages, page, dk), np.float32)
     bt = np.zeros((b, w), np.int32)
     order = np.arange(num_pages)
     if shuffle_seed is not None:
@@ -45,7 +45,7 @@ def paginate(c, kv_lens, page, *, num_pages, shuffle_seed=None):
             nxt += 1
             lo = j * page
             hi = min(lo + page, int(kv_lens[bb]))
-            pool[pid, : hi - lo] = np.asarray(c[bb, lo:hi])
+            pool[pid, : hi - lo, :dk] = np.asarray(c[bb, lo:hi])
             bt[bb, j] = pid
     return jnp.asarray(pool), jnp.asarray(bt)
 
@@ -55,8 +55,9 @@ GEOMETRIES = [
     pytest.param(1, 4, 128, 128, 64, [60], id="short-single-page"),
     pytest.param(3, 8, 128, 64, 64, [200, 37, 130], id="ragged-batch"),
     pytest.param(1, 8, 576, 512, 128, [1210], id="paper-multi-page-long"),
-    # More query rows than one VMEM tile: the queue kernel runs 3 row
-    # tiles of 368 (4 pad rows), like a 32-token prefill chunk at 128 heads.
+    # More query rows than one combine tile: the combine kernel runs 3 row
+    # tiles of 368 (4 pad rows) while the queue kernel holds all 1100 rows
+    # in one program, like a 32-token prefill chunk at 128 heads.
     pytest.param(2, 1100, 128, 64, 64, [200, 37], id="row-tiled"),
 ]
 

@@ -17,7 +17,7 @@ from repro.kernels.decode_schedule import (
     build_prefix_schedule,
     prefix_queue_grid_items,
 )
-from repro.runtime.kv_cache import PagedKVCache
+from repro.runtime.kv_cache import PagedKVCache, pool_shape
 from repro.runtime.serve_loop import PagedDecodeSession
 
 INTERP = dict(interpret=True)
@@ -309,7 +309,7 @@ def test_session_fork_rejects_dead_parent():
 
 def test_ops_validates_block_k_multiple_of_page():
     q = bf16ish((1, 1, 2, 64), 1)
-    pool = bf16ish((4, 32, 64), 2)
+    pool = bf16ish(pool_shape(4, 32, 64), 2)
     bt = jnp.zeros((1, 4), jnp.int32)
     with pytest.raises(ValueError, match="multiple of the pool's page_size"):
         ops.mla_decode_paged(
@@ -320,7 +320,7 @@ def test_ops_validates_block_k_multiple_of_page():
 
 def test_ops_validates_table_width_covers_kv_len():
     q = bf16ish((1, 1, 2, 64), 1)
-    pool = bf16ish((4, 32, 64), 2)
+    pool = bf16ish(pool_shape(4, 32, 64), 2)
     bt = jnp.zeros((1, 2), jnp.int32)  # reach: 64 rows
     with pytest.raises(ValueError, match="exceeds the block table's reach"):
         ops.mla_decode_paged(
@@ -331,7 +331,7 @@ def test_ops_validates_table_width_covers_kv_len():
 
 def test_ops_validates_table_shape_and_widths():
     q = bf16ish((2, 1, 2, 64), 1)
-    pool = bf16ish((4, 32, 64), 2)
+    pool = bf16ish(pool_shape(4, 32, 64), 2)
     with pytest.raises(ValueError, match="block_tables must be"):
         ops.mla_decode_paged(
             q, pool, jnp.zeros((1, 2), jnp.int32),
@@ -339,7 +339,7 @@ def test_ops_validates_table_shape_and_widths():
         )
     with pytest.raises(ValueError, match="share D_k"):
         ops.mla_decode_paged(
-            bf16ish((1, 1, 2, 32), 3), pool, jnp.zeros((1, 2), jnp.int32),
+            bf16ish((1, 1, 2, 192), 3), pool, jnp.zeros((1, 2), jnp.int32),
             jnp.asarray([8], jnp.int32), d_v=32, scale=0.1, **INTERP,
         )
 
@@ -348,7 +348,7 @@ def test_ops_rejects_mismatched_schedule_types():
     from repro.kernels.decode_schedule import build_schedule
 
     q = bf16ish((1, 1, 2, 64), 1)
-    pool = bf16ish((4, 32, 64), 2)
+    pool = bf16ish(pool_shape(4, 32, 64), 2)
     bt = jnp.zeros((1, 4), jnp.int32)
     plain = build_schedule([40], block_k=32)
     with pytest.raises(ValueError, match="PrefixSchedule"):

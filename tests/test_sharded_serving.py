@@ -32,6 +32,7 @@ from repro.core.distributed import combine_shard_partials
 from repro.kernels import ops
 from repro.kernels.decode_schedule import route_request, shard_work_balance
 from repro.models.model_zoo import build_model
+from repro.runtime.kv_cache import pool_shape
 from repro.runtime.serve_loop import (
     PagedServingSession,
     ShardedPagedServingSession,
@@ -158,10 +159,10 @@ def _paginate(rows, page):
     """One request's (n, dk) rows -> a private page pool + block table."""
     n, dk = rows.shape
     n_pages = max(-(-n // page), 1)
-    pool = np.zeros((n_pages + 1, page, dk), np.float32)  # +1 dummy page
+    pool = np.zeros(pool_shape(n_pages + 1, page, dk), np.float32)  # +1 dummy
     for j in range(n_pages):
         hi = min((j + 1) * page, n)
-        pool[j, : hi - j * page] = rows[j * page : hi]
+        pool[j, : hi - j * page, :dk] = rows[j * page : hi]
     bt = np.arange(n_pages, dtype=np.int32)[None, :]
     return jnp.asarray(pool), jnp.asarray(bt)
 

@@ -26,7 +26,7 @@ import pytest
 from repro.configs import get_config
 from repro.kernels import ops
 from repro.models.model_zoo import build_model
-from repro.runtime.kv_cache import PagedKVCache
+from repro.runtime.kv_cache import PagedKVCache, pool_shape
 from repro.runtime.serve_loop import (
     NGramProposer,
     PagedServingSession,
@@ -244,13 +244,13 @@ def _spec_kernel_args(kv_lens=(21, 13), sq=3, hq=2, dk=32, page=8):
     c = rng.normal(0, 0.3, (b, max(kv_lens), dk)).astype(np.float32)
     num_pages = sum(-(-l // page) for l in kv_lens) + 1
     w = max(-(-l // page) for l in kv_lens)
-    pool = np.zeros((num_pages, page, dk), np.float32)
+    pool = np.zeros(pool_shape(num_pages, page, dk), np.float32)
     bt = np.zeros((b, w), np.int32)
     nxt = 0
     for bb, l in enumerate(kv_lens):
         for j in range(-(-l // page)):
             hi = min((j + 1) * page, l)
-            pool[nxt, : hi - j * page] = c[bb, j * page : hi]
+            pool[nxt, : hi - j * page, :dk] = c[bb, j * page : hi]
             bt[bb, j] = nxt
             nxt += 1
     return q, jnp.asarray(pool), jnp.asarray(bt), jnp.asarray(

@@ -14,6 +14,7 @@ import pytest
 from repro.kernels import ops
 from repro.kernels.decode_schedule import build_schedule
 from repro.kernels.mla_decode_combine import combine_split_partials
+from repro.runtime.kv_cache import pool_shape
 
 INTERP = dict(interpret=True)
 PARITY_ATOL = 2e-3
@@ -28,13 +29,13 @@ def paginate_linear(c, kv_lens, page):
     b, s, dk = c.shape
     num_pages = sum(-(-int(l) // page) for l in kv_lens) + 1
     w = max(max(-(-int(l) // page) for l in kv_lens), 1)
-    pool = np.zeros((num_pages, page, dk), np.float32)
+    pool = np.zeros(pool_shape(num_pages, page, dk), np.float32)
     bt = np.zeros((b, w), np.int32)
     nxt = 0
     for bb, l in enumerate(kv_lens):
         for j in range(-(-int(l) // page)):
             hi = min((j + 1) * page, int(l))
-            pool[nxt, : hi - j * page] = np.asarray(c[bb, j * page : hi])
+            pool[nxt, : hi - j * page, :dk] = np.asarray(c[bb, j * page : hi])
             bt[bb, j] = nxt
             nxt += 1
     return jnp.asarray(pool), jnp.asarray(bt)

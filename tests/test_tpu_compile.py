@@ -1,10 +1,12 @@
-"""Compile-only checks of the paged MLA kernels for a described TPU v5e.
+"""Compile-only checks of the decode kernels for a described TPU v5e.
 
 The TPU compiler refuses what interpret mode accepts: vector loads from
 scalar memory, DMA slices that are not whole 128-lane tiles, kernels that
 outgrow VMEM.  These tests lower and compile the serving path's kernels at
-the deepseek-v2-mla widths (Dk = 576, Dv = 512, 128 heads) for one chip of a
-described ``v5e:2x2`` topology; nothing runs, so no chip is needed.
+the deepseek-v2-mla widths (Dk = 576, Dv = 512, 128 heads), and the dense
+path's contiguous MLA and GQA decode kernels at repo models' widths, for one
+chip of a described ``v5e:2x2`` topology; nothing runs, so no chip is
+needed.
 """
 
 from __future__ import annotations
@@ -16,11 +18,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import get_config
 from repro.kernels import decode_schedule as sched
+from repro.kernels import mla_decode as contiguous
 from repro.kernels import mla_decode_combine as combine
 from repro.kernels import mla_decode_paged as paged
 from repro.kernels import ops
-from repro.kernels.mla_decode import lane_pad, row_tiling
+from repro.kernels.mla_decode import lane_pad
+from repro.models.mla_layer import init_latent_cache
+from repro.runtime.kv_cache import pool_shape, scale_pool_shape
 
 HEADS, DK, DV = 128, 576, 512
 PAGE, BLOCK_K = 32, 512
@@ -84,14 +90,14 @@ def test_queue_decode_compiles(one_chip, kv_dtype):
 
     s = one_chip
     scales = (
-        _spec(s, (NUM_PAGES, lane_pad(PAGE)), jnp.float32)
+        _spec(s, scale_pool_shape(NUM_PAGES, PAGE), jnp.float32)
         if spec.quantized
         else None
     )
     _compile(
         step,
         _spec(s, (b, 1, HEADS, DK), jnp.bfloat16),
-        _spec(s, (NUM_PAGES, PAGE, lane_pad(DK)), spec.dtype),
+        _spec(s, pool_shape(NUM_PAGES, PAGE, DK), spec.dtype),
         scales,
         _spec(s, (b, width), jnp.int32),
         _spec(s, (b,), jnp.int32),
@@ -99,9 +105,10 @@ def test_queue_decode_compiles(one_chip, kv_dtype):
 
 
 def test_group_prefix_compiles(one_chip):
-    """Shared-prefix pass: 4 members' rows stacked over one group's pages."""
+    """Shared-prefix pass: 8 members' rows (1024) stacked over one group's
+    pages, more than one row tile, still one DMA per page."""
     s = one_chip
-    b, n_groups, gmax, items = 8, 2, 4, 8
+    b, n_groups, gmax, items = 8, 2, 8, 8
     width = NUM_PAGES // 8
 
     def group(q, pages, bt, q_pos, member, rep, plen, *queue):
@@ -113,7 +120,7 @@ def test_group_prefix_compiles(one_chip):
     _compile(
         group,
         _spec(s, (b, HEADS, lane_pad(DK)), jnp.bfloat16),
-        _spec(s, (NUM_PAGES, PAGE, lane_pad(DK)), jnp.bfloat16),
+        _spec(s, pool_shape(NUM_PAGES, PAGE, DK), jnp.bfloat16),
         _spec(s, (b, width), jnp.int32),
         _spec(s, (b, HEADS), jnp.int32),
         _spec(s, (n_groups, gmax), jnp.int32),
@@ -137,8 +144,15 @@ def test_combine_compiles(one_chip):
 
 def test_prefill_rows_fit_vmem(one_chip):
     """A 32-token prefill chunk at 128 heads is 4096 query rows: more than
-    one VMEM block holds, so the queue kernel tiles them."""
-    assert row_tiling(32 * HEADS)[0] > 1
+    the default scoped VMEM holds.  The queue kernel keeps them all in one
+    program (one DMA per page), updates them in row tiles, and asks for the
+    VMEM it needs."""
+    n_tiles, tile_g = contiguous.row_tiling(32 * HEADS)
+    assert n_tiles > 1
+    assert contiguous.vmem_limit_bytes(
+        n_tiles * tile_g, tile_g, d_k=lane_pad(DK), d_v=DV, block_k=BLOCK_K,
+        q_dtype=jnp.bfloat16, kv_dtype=jnp.bfloat16,
+    ) > contiguous.DEFAULT_SCOPED_VMEM
     schedule = _decode_schedule([2048])
 
     def prefill(q, pages, bt, kv_len, q_off):
@@ -151,8 +165,90 @@ def test_prefill_rows_fit_vmem(one_chip):
     _compile(
         prefill,
         _spec(s, (1, 32, HEADS, DK), jnp.bfloat16),
-        _spec(s, (NUM_PAGES, PAGE, lane_pad(DK)), jnp.bfloat16),
+        _spec(s, pool_shape(NUM_PAGES, PAGE, DK), jnp.bfloat16),
         _spec(s, (1, NUM_PAGES // 8), jnp.int32),
         _spec(s, (1,), jnp.int32),
         _spec(s, (1,), jnp.int32),
+    )
+
+
+def test_prefill_rows_past_vmem_rejected():
+    """A 64-token chunk at 128 heads (8192 rows) would need more VMEM than
+    a core has: the queue kernel says so while tracing, before any compile."""
+    schedule = _decode_schedule([2048])
+
+    def prefill(q, pages, bt, kv_len, q_off):
+        return ops.mla_decode_paged(
+            q, pages, bt, kv_len, d_v=DV, scale=SCALE, q_offset=q_off,
+            block_k=BLOCK_K, schedule=schedule,
+        )
+
+    with pytest.raises(ValueError, match="VMEM"):
+        jax.eval_shape(
+            prefill,
+            jax.ShapeDtypeStruct((1, 64, HEADS, DK), jnp.bfloat16),
+            jax.ShapeDtypeStruct(pool_shape(NUM_PAGES, PAGE, DK), jnp.bfloat16),
+            jax.ShapeDtypeStruct((1, NUM_PAGES // 8), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32),
+        )
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr``, nested jit bodies included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for p in eqn.params.values():
+            if hasattr(p, "jaxpr"):
+                yield from _eqns(getattr(p.jaxpr, "jaxpr", p.jaxpr))
+
+
+def test_contiguous_mla_decode_compiles(one_chip):
+    """The dense path's MLA decode over the cache ``init_latent_cache``
+    allocates (rows lane-padded to 640): compiles, and copies no cache."""
+    cfg = get_config("deepseek-v2-mla")
+    b, max_len = 8, 4096
+    cache = jax.eval_shape(lambda: init_latent_cache(cfg, b, max_len))["c"]
+    assert cache.shape == (b, max_len, lane_pad(DK))
+
+    def step(q, c, kv_len):
+        return ops.mla_decode(q, c, d_v=DV, scale=SCALE, kv_len=kv_len)
+
+    args = (
+        jax.ShapeDtypeStruct((b, 1, HEADS, DK), jnp.bfloat16),
+        jax.ShapeDtypeStruct(cache.shape, cache.dtype),
+        jax.ShapeDtypeStruct((b,), jnp.int32),
+    )
+    jaxpr = jax.make_jaxpr(step)(*args)
+    padded = [
+        eqn for eqn in _eqns(jaxpr.jaxpr)
+        if eqn.primitive.name == "pad" and eqn.outvars[0].aval.ndim == 3
+        and eqn.outvars[0].aval.shape[1] == max_len
+    ]
+    assert not padded, "the lane-padded cache must reach the kernel uncopied"
+    s = one_chip
+    _compile(step, *(_spec(s, a.shape, a.dtype) for a in args))
+
+
+@pytest.mark.parametrize("name", ["gemma2-2b", "internlm2-20b"])
+def test_gqa_decode_compiles(one_chip, name):
+    """GQA decode at a repo model's widths (kv heads, head_dim, window and
+    softcap from its config), 8 requests over a 4096-row cache."""
+    cfg = get_config(name)
+    b, s_len = 8, 4096
+
+    def step(q, k, v, kv_len):
+        return ops.gqa_attention(
+            q, k, v, causal=True, window=cfg.window,
+            softcap=cfg.attn_softcap, scale=cfg.head_dim**-0.5, kv_len=kv_len,
+        )
+
+    s = one_chip
+    kv = (b, s_len, cfg.n_kv_heads, cfg.head_dim)
+    _compile(
+        step,
+        _spec(s, (b, 1, cfg.n_heads, cfg.head_dim), jnp.bfloat16),
+        _spec(s, kv, jnp.bfloat16),
+        _spec(s, kv, jnp.bfloat16),
+        _spec(s, (b,), jnp.int32),
     )
