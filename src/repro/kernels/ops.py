@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.tracing import span
 from repro.kernels import decode_schedule as _sched
 from repro.kernels import flash_prefill as _prefill
 from repro.kernels import gqa_decode as _gqa
@@ -131,9 +132,11 @@ def _validate_paged_geometry(
             f" or leave block_k=None for the §4.2 default)"
         )
     # Table-width bound: every valid token must resolve to a table entry.
-    # kv_len is host data on the serving path; skip silently when traced.
+    # A device kv_len is read back to the host here (every call on the
+    # serving path); skip silently when traced.
     if not isinstance(kv_len, jax.core.Tracer):
-        lens = np.asarray(kv_len).reshape(-1)
+        with span("serve.sync", site="kv_len_check"):
+            lens = np.asarray(kv_len).reshape(-1)
         if lens.size and int(lens.max()) > w * page_size:
             worst = int(np.argmax(lens))
             raise ValueError(
@@ -188,7 +191,8 @@ def _validate_q_positions(q_positions, b, sq, kv_len, scheduler, q_offset, causa
             f"has {arr[bad].tolist()}"
         )
     if not isinstance(kv_len, jax.core.Tracer):
-        lens = np.asarray(kv_len).reshape(-1)
+        with span("serve.sync", site="q_positions_check"):
+            lens = np.asarray(kv_len).reshape(-1)
         over = arr >= lens[:, None]
         if np.any(over):
             bad = int(np.argmax(np.any(over, axis=1)))
@@ -363,9 +367,12 @@ def mla_decode_paged(
             )
         ps = schedule
         if ps is None:
+            with span("serve.sync", site="schedule"):
+                kv_host = np.asarray(kv_len)
+                bt_host = np.asarray(block_tables)
             ps = _sched.build_prefix_schedule(
-                np.asarray(kv_len),
-                np.asarray(block_tables),
+                kv_host,
+                bt_host,
                 page_size=page_size,
                 block_k=block_k,
                 num_splits=num_splits,
@@ -420,8 +427,10 @@ def mla_decode_paged(
         return out.reshape(b, sq, hq, d_v)
 
     if schedule is None:
+        with span("serve.sync", site="schedule"):
+            kv_host = np.asarray(kv_len)
         schedule = _sched.build_schedule(
-            np.asarray(kv_len), block_k=block_k, num_splits=num_splits
+            kv_host, block_k=block_k, num_splits=num_splits
         )
     o_part, lse = _mla_paged.mla_decode_paged_queue_rows(
         q_rows,

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.tracing import span
 from repro.models import layers, moe
 from repro.models.attention_layer import gqa_apply, gqa_init, init_kv_cache
 from repro.models.mla_layer import (
@@ -505,12 +506,15 @@ def lm_prefill_paged(
         tok[0, :valid] = tokens[s0 : s0 + valid]
         abs0 = start_pos + s0
         positions = jnp.asarray(abs0 + np.arange(chunk, dtype=np.int32))[None]
-        plan = cache.reserve(rid, valid)
-        bt, kv_len = cache.block_table([rid], width=tw)
-        # One schedule per chunk, shared by all L layers (same block table
-        # and kv_len everywhere) — kv_len is host numpy here, so this costs
-        # no device sync.
-        schedule = _sched.build_schedule(kv_len, block_k=block_k, num_splits=1)
+        with span("serve.schedule"):
+            plan = cache.reserve(rid, valid)
+            bt, kv_len = cache.block_table([rid], width=tw)
+            # One schedule per chunk, shared by all L layers (same block
+            # table and kv_len everywhere) — kv_len is host numpy here, so
+            # this costs no device sync.
+            schedule = _sched.build_schedule(
+                kv_len, block_k=block_k, num_splits=1
+            )
         bt, kv_len = jnp.asarray(bt), jnp.asarray(kv_len)
         q_off = jnp.full((1,), abs0, jnp.int32)
         x = _paged_embed(params["embed"], jnp.asarray(tok), cfg=cfg)
@@ -601,49 +605,50 @@ def lm_decode_step_paged(
     if block_k is None:
         block_k = ops.default_paged_block_k(cache.page_size, tw)
 
-    start = np.asarray([cache.seq_len(r) for r in rids], np.int32)
-    positions = start[:, None] + np.arange(s, dtype=np.int32)[None, :]
-    need = sum(cache.pages_needed_for_append(r, s) for r in rids)
-    if need > cache.num_free_pages:
-        raise OutOfPagesError(
-            f"decode step needs {need} new pages for {len(rids)} appends "
-            f"of {s} row(s); only {cache.num_free_pages} free — evict and "
-            f"retry"
-        )
-    plans = [cache.reserve(r, s) for r in rids]
-    # Flatten each request's reserve chunks to one (page, offset) per row:
-    # a speculative run of S rows may straddle a page boundary mid-request,
-    # so the scatter write needs per-row destinations.
-    pids = np.empty((len(rids) * s,), np.int32)
-    offs = np.empty((len(rids) * s,), np.int32)
-    w = 0
-    for plan in plans:
-        for pid, off0, m in plan:
-            pids[w : w + m] = pid
-            offs[w : w + m] = off0 + np.arange(m, dtype=np.int32)
-            w += m
-    bt, kv_len = cache.block_table(rids, width=tw)
+    with span("serve.schedule"):
+        start = np.asarray([cache.seq_len(r) for r in rids], np.int32)
+        positions = start[:, None] + np.arange(s, dtype=np.int32)[None, :]
+        need = sum(cache.pages_needed_for_append(r, s) for r in rids)
+        if need > cache.num_free_pages:
+            raise OutOfPagesError(
+                f"decode step needs {need} new pages for {len(rids)} appends "
+                f"of {s} row(s); only {cache.num_free_pages} free — evict "
+                f"and retry"
+            )
+        plans = [cache.reserve(r, s) for r in rids]
+        # Flatten each request's reserve chunks to one (page, offset) per
+        # row: a speculative run of S rows may straddle a page boundary
+        # mid-request, so the scatter write needs per-row destinations.
+        pids = np.empty((len(rids) * s,), np.int32)
+        offs = np.empty((len(rids) * s,), np.int32)
+        w = 0
+        for plan in plans:
+            for pid, off0, m in plan:
+                pids[w : w + m] = pid
+                offs[w : w + m] = off0 + np.arange(m, dtype=np.int32)
+                w += m
+        bt, kv_len = cache.block_table(rids, width=tw)
 
-    # One schedule per step, shared by all L layers (they see the same
-    # block tables): the memoizing scheduler additionally reuses it across
-    # steps until a request crosses a block_k boundary or the live set
-    # changes (extra_key).
-    if scheduler is not None:
-        if prefix_sharing:
-            schedule = scheduler.schedule_prefix(
-                kv_len, bt, page_size=cache.page_size, extra_key=extra_key
+        # One schedule per step, shared by all L layers (they see the same
+        # block tables): the memoizing scheduler additionally reuses it
+        # across steps until a request crosses a block_k boundary or the
+        # live set changes (extra_key).
+        if scheduler is not None:
+            if prefix_sharing:
+                schedule = scheduler.schedule_prefix(
+                    kv_len, bt, page_size=cache.page_size, extra_key=extra_key
+                )
+            else:
+                schedule = scheduler.schedule(kv_len, extra_key=extra_key)
+        elif prefix_sharing:
+            schedule = _sched.build_prefix_schedule(
+                kv_len, bt, page_size=cache.page_size, block_k=block_k,
+                num_splits=num_splits,
             )
         else:
-            schedule = scheduler.schedule(kv_len, extra_key=extra_key)
-    elif prefix_sharing:
-        schedule = _sched.build_prefix_schedule(
-            kv_len, bt, page_size=cache.page_size, block_k=block_k,
-            num_splits=num_splits,
-        )
-    else:
-        schedule = _sched.build_schedule(
-            kv_len, block_k=block_k, num_splits=num_splits
-        )
+            schedule = _sched.build_schedule(
+                kv_len, block_k=block_k, num_splits=num_splits
+            )
 
     bt, kv_len = jnp.asarray(bt), jnp.asarray(kv_len)
     b = len(rids)

@@ -17,7 +17,21 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro.core.tracing import Tracer, span
 from repro.runtime import sharding
+
+# The serving path's spans (``repro.core.tracing``), each reported by
+# ``PagedServingSession.work_stats``.  "window", "step", "add_request" and
+# "finish" are the chip benchmark's own span names; no span here takes one.
+SERVE_SPANS = (
+    "serve.step",  # the whole of PagedServingSession.step()
+    "serve.decode",  # the decode pass, through the greedy readback
+    "serve.prefill",  # one budgeted prefill slice, first-token read included
+    "serve.schedule",  # reserve, block tables, decode schedules, slice plans
+    "serve.sync",  # a device-to-host read
+    "serve.admit",  # add_request
+    "serve.finish",  # finish
+)
 
 
 def jit_serve_fns(
@@ -501,20 +515,21 @@ class PagedServingSession:
         self.accepted_tokens = 0
         self.page_dmas = 0
         self.rows_attended = 0
-        # Virtual work clock + per-request latency accounting.  One work
-        # unit = one model invocation: a fused decode launch or one padded
-        # prefill chunk — a deterministic step-count proxy for latency
-        # (interpret-mode wall time is runner noise).  ``prefill_stall_
-        # steps`` counts chunks prefilled *synchronously* while decoders
-        # were live (the phase-separated head-of-line stall; identically 0
-        # under budgeted interleaving).
+        # Virtual work clock.  One work unit = one model invocation: a
+        # fused decode launch or one padded prefill chunk — a deterministic
+        # step-count proxy (``ServeSupervisor(arrival_unit="work_units")``).
+        # ``prefill_stall_steps`` counts chunks prefilled *synchronously*
+        # while decoders were live (the phase-separated head-of-line stall;
+        # identically 0 under budgeted interleaving).
         self.work_units = 0
         self.prefill_chunks = 0
         self.prefill_stall_steps = 0
-        self.first_tokens = 0
-        self.ttft_units_total = 0
-        self.max_inter_token_units = 0
-        self._lat: dict[int, dict] = {}
+        # Wall-clock spans of add_request / step / finish and what runs
+        # inside them (SERVE_SPANS), and the wait of budgeted prompts from
+        # their enqueue to the start of their first prefill slice.
+        self.tracer = Tracer(SERVE_SPANS)
+        self.prefill_wait_s = 0.0
+        self.prefill_waits = 0
         # Recoverable eviction (suspend/resume by replay) + chaos state.
         # ``_family`` maps a forked child to (parent rid, shared rows) so
         # suspend records the alias point and resume can re-alias it.
@@ -604,7 +619,8 @@ class PagedServingSession:
             "suspended": len(self.suspended),
             "replay_prefill_tokens": self.replay_prefill_tokens,
             "replay_mismatches": self.replay_mismatches,
-            # Interleaving / latency proxies (virtual work clock).
+            # Interleaving proxies (virtual work clock), then wall clock:
+            # the prefill queue's wait and the span totals.
             "work_units": self.work_units,
             "prefill_chunks": self.prefill_chunks,
             "prefill_stall_steps": self.prefill_stall_steps,
@@ -612,9 +628,9 @@ class PagedServingSession:
             "prefill_backlog_tokens": sum(
                 len(p["prompt"]) - p["done"] for p in self._pending
             ),
-            "first_tokens": self.first_tokens,
-            "ttft_units_total": self.ttft_units_total,
-            "max_inter_token_units": self.max_inter_token_units,
+            "prefill_wait_s": self.prefill_wait_s,
+            "prefill_waits": self.prefill_waits,
+            **self.tracer.stats(),
         }
 
     @property
@@ -626,7 +642,7 @@ class PagedServingSession:
         """Every rid holding pool pages: live decoders + pending prompts."""
         return list(self.active) + [p["rid"] for p in self._pending]
 
-    # -- latency / work accounting -------------------------------------- #
+    # -- work accounting ------------------------------------------------ #
     def _count_prefill(self, n_tokens: int, interleaved: bool = False):
         chunks = -(-n_tokens // self.prefill_chunk)
         self.prefill_chunks += chunks
@@ -636,25 +652,6 @@ class PagedServingSession:
             # virtual step those requests spent stalled.
             self.prefill_stall_steps += chunks
 
-    def _note_admit(self, rid: int) -> None:
-        vt = self.work_units
-        self._lat.setdefault(rid, {"admit": vt, "first": None, "last": vt})
-
-    def _note_emit(self, rid: int) -> None:
-        vt = self.work_units
-        rec = self._lat.setdefault(
-            rid, {"admit": vt, "first": None, "last": vt}
-        )
-        if rec["first"] is None:
-            rec["first"] = vt
-            self.first_tokens += 1
-            self.ttft_units_total += vt - rec["admit"]
-        else:
-            gap = vt - rec["last"]
-            if gap > self.max_inter_token_units:
-                self.max_inter_token_units = gap
-        rec["last"] = vt
-
     # -- admission / branching ----------------------------------------- #
     def _admit(self, rid: int, first_token: int) -> int:
         self.active.append(rid)
@@ -663,7 +660,6 @@ class PagedServingSession:
         # view of that very object — replacing it would orphan their alias.
         self.outputs.setdefault(rid, []).append(first_token)
         self.last_token[rid] = first_token
-        self._note_emit(rid)
         return rid
 
     def add_request(self, prompt_tokens) -> int | None:
@@ -676,6 +672,10 @@ class PagedServingSession:
         (refcount bumps over all layers, exactly like :meth:`fork`), and
         only the divergent tail prefills — no caller-named parent needed.
         """
+        with self.tracer.active(), span("serve.admit", rid=self._next_id):
+            return self._add_request(prompt_tokens)
+
+    def _add_request(self, prompt_tokens) -> int | None:
         from repro.models import transformer as _tf
 
         prompt = list(map(int, prompt_tokens))
@@ -723,7 +723,6 @@ class PagedServingSession:
             self.cache.free(rid)  # adoption refs drop; pins are untouched
             return None
         self._prompt[rid] = prompt
-        self._note_admit(rid)
         if self.prefill_budget is not None:
             # Budgeted interleaving: admission only *enqueues* — the
             # matched prefix pages are already adopted, and step() advances
@@ -732,7 +731,8 @@ class PagedServingSession:
             # it before the first token lands.
             self.outputs[rid] = []
             self._pending.append(
-                {"rid": rid, "prompt": prompt, "done": matched}
+                {"rid": rid, "prompt": prompt, "done": matched,
+                 "enqueued": time.perf_counter()}
             )
             return rid
         self._prefill_shapes.add((1, self.prefill_chunk))
@@ -757,7 +757,9 @@ class PagedServingSession:
             # retained prefixes, so concurrent same-template admissions
             # alias this request's pages without waiting for it to finish.
             self._retain_prompt(rid)
-        return self._admit(rid, int(jnp.argmax(logits[0])))
+        with span("serve.sync", site="first_token"):
+            first = int(jnp.argmax(logits[0]))
+        return self._admit(rid, first)
 
     def _retain_prompt(self, rid: int) -> None:
         """Pin ``rid``'s complete prompt blocks into the trie (idempotent:
@@ -800,8 +802,6 @@ class PagedServingSession:
         self.last_token[child] = self.last_token[rid]
         self._prompt[child] = list(self._prompt[rid])
         self._family[child] = (rid, self.cache.seq_len(child))
-        vt = self.work_units
-        self._lat[child] = {"admit": vt, "first": vt, "last": vt}
         return child
 
     def admit_with_prefix(
@@ -846,7 +846,6 @@ class PagedServingSession:
         ctx = (self._prompt[parent_rid] + self.outputs[parent_rid])[:-1]
         self._prompt[child] = ctx[:start] + suffix
         self._family[child] = (parent_rid, start)
-        self._note_admit(child)
         self._prefill_shapes.add((1, self.prefill_chunk))
         logits = _tf.lm_prefill_paged(
             self.params,
@@ -890,6 +889,10 @@ class PagedServingSession:
         final slice lands joins the live batch with its first token and
         decodes from the next step.
         """
+        with self.tracer.active(), span("serve.step", batch=len(self.active)):
+            self._step()
+
+    def _step(self) -> None:
         from repro.kernels.decode_schedule import (
             PrefixSchedule,
             prefix_queue_grid_items,
@@ -925,33 +928,36 @@ class PagedServingSession:
         # Pre-append lengths: truncation targets and accounting both need
         # the lengths the *schedule* saw, not the post-rollback ones.
         pre = {r: self.cache.seq_len(r) for r in rids}
-        logits = _tf.lm_decode_step_paged(
-            self.params,
-            tokens,
-            cfg=self.cfg,
-            cache=self.cache,
-            rids=rids,
-            scheduler=self._scheduler,
-            prefix_sharing=self.prefix_sharing,
-            # The trie epoch folds eviction/insert/split churn into the
-            # memo key: adopted-page aliasing changes grouping structure
-            # even when block counts and the live set look unchanged.
-            extra_key=(
-                (tuple(rids), self.trie.epoch)
-                if self.trie is not None
-                else tuple(rids)
-            ),
-            table_width=self.table_width,
-            block_k=self.block_k,
-            num_splits=self.num_splits,
-            interpret=self.interpret,
-            layer_params=self._layers,
-            compute_dtype=self.compute_dtype,
-            head_shards=self.head_shards,
-            # One decode program per session, whatever the live count.
-            batch_rows=self.max_batch,
-        )
-        greedy = np.asarray(jnp.argmax(logits, axis=-1), np.int32)  # (B, s)
+        with span("serve.decode", batch=len(rids)):
+            logits = _tf.lm_decode_step_paged(
+                self.params,
+                tokens,
+                cfg=self.cfg,
+                cache=self.cache,
+                rids=rids,
+                scheduler=self._scheduler,
+                prefix_sharing=self.prefix_sharing,
+                # The trie epoch folds eviction/insert/split churn into the
+                # memo key: adopted-page aliasing changes grouping structure
+                # even when block counts and the live set look unchanged.
+                extra_key=(
+                    (tuple(rids), self.trie.epoch)
+                    if self.trie is not None
+                    else tuple(rids)
+                ),
+                table_width=self.table_width,
+                block_k=self.block_k,
+                num_splits=self.num_splits,
+                interpret=self.interpret,
+                layer_params=self._layers,
+                compute_dtype=self.compute_dtype,
+                head_shards=self.head_shards,
+                # One decode program per session, whatever the live count.
+                batch_rows=self.max_batch,
+            )
+            with span("serve.sync", site="greedy"):
+                # (B, s) greedy picks, one per fed row
+                greedy = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         self.work_units += 1  # one fused decode launch
         for i, r in enumerate(rids):
             # Accept the longest prefix where draft d_{m+1} equals the
@@ -964,7 +970,6 @@ class PagedServingSession:
             emitted = [int(t) for t in greedy[i, : m + 1]]
             self.outputs[r].extend(emitted)
             self.last_token[r] = emitted[-1]
-            self._note_emit(r)
             if m + 1 < s:
                 # Roll back rejected draft rows: keep the pending token's
                 # row plus the m accepted draft rows.
@@ -1015,11 +1020,12 @@ class PagedServingSession:
         from repro.models import transformer as _tf
 
         pending = list(self._pending)
-        slices = plan_prefill_slices(
-            [len(p["prompt"]) - p["done"] for p in pending],
-            self.prefill_budget,
-            self.prefill_chunk,
-        )
+        with span("serve.schedule"):
+            slices = plan_prefill_slices(
+                [len(p["prompt"]) - p["done"] for p in pending],
+                self.prefill_budget,
+                self.prefill_chunk,
+            )
         for ent, take in zip(pending, slices):
             if take <= 0:
                 continue
@@ -1028,29 +1034,37 @@ class PagedServingSession:
                 continue
             final = ent["done"] + take == len(prompt)
             self._prefill_shapes.add((1, self.prefill_chunk))
-            logits = _tf.lm_prefill_paged(
-                self.params,
-                prompt[ent["done"] : ent["done"] + take],
-                cfg=self.cfg,
-                cache=self.cache,
-                rid=rid,
-                start_pos=ent["done"],
-                chunk=self.prefill_chunk,
-                table_width=self.table_width,
-                block_k=self.block_k,
-                interpret=self.interpret,
-                layer_params=self._layers,
-                compute_dtype=self.compute_dtype,
-                head_shards=self.head_shards,
-                need_logits=final,
-            )
+            with span("serve.prefill", rid=rid, tokens=take):
+                enqueued = ent.pop("enqueued", None)
+                if enqueued is not None:
+                    self.prefill_wait_s += time.perf_counter() - enqueued
+                    self.prefill_waits += 1
+                logits = _tf.lm_prefill_paged(
+                    self.params,
+                    prompt[ent["done"] : ent["done"] + take],
+                    cfg=self.cfg,
+                    cache=self.cache,
+                    rid=rid,
+                    start_pos=ent["done"],
+                    chunk=self.prefill_chunk,
+                    table_width=self.table_width,
+                    block_k=self.block_k,
+                    interpret=self.interpret,
+                    layer_params=self._layers,
+                    compute_dtype=self.compute_dtype,
+                    head_shards=self.head_shards,
+                    need_logits=final,
+                )
+                if final:
+                    with span("serve.sync", site="first_token"):
+                        first = int(jnp.argmax(logits[0]))
             self._count_prefill(take, interleaved=True)
             ent["done"] += take
             if final:
                 self._pending.remove(ent)
                 if self.trie is not None:
                     self._retain_prompt(rid)
-                self._admit(rid, int(jnp.argmax(logits[0])))
+                self._admit(rid, first)
 
     def finish(self, rid: int) -> list[int]:
         """Retire ``rid``: pages return to the pool (aliased prefix pages
@@ -1062,6 +1076,10 @@ class PagedServingSession:
         list, and the next admission sharing the prompt prefix adopts
         them instead of re-prefilling.
         """
+        with self.tracer.active(), span("serve.finish", rid=rid):
+            return self._finish(rid)
+
+    def _finish(self, rid: int) -> list[int]:
         if rid not in self.active:
             pend = next(
                 (p for p in self._pending if p["rid"] == rid), None
@@ -1194,7 +1212,6 @@ class PagedServingSession:
             self.cache.alloc(rid)
             self._prompt[rid] = prompt
             self.outputs[rid] = rec.outputs
-            self._note_admit(rid)
             if self.prefill_budget is not None:
                 self._pending.append(
                     {"rid": rid, "prompt": prompt, "done": 0}
@@ -1820,14 +1837,15 @@ class ShardedPagedServingSession:
                 "prefill_stall_steps",
                 "prefill_pending",
                 "prefill_backlog_tokens",
-                "first_tokens",
-                "ttft_units_total",
+                "prefill_wait_s",
+                "prefill_waits",
             )
         }
-        # Worst-case inter-token gap is a max, not a sum.
-        agg["max_inter_token_units"] = max(
-            st["max_inter_token_units"] for st in per_shard
-        )
+        # Span totals (``span_n.*``, ``span_s.*``, ``span_self_s.*``) sum
+        # over the shards' own tracers.
+        for k in sorted({k for st in per_shard for k in st}):
+            if k.startswith("span_"):
+                agg[k] = sum(st.get(k, 0) for st in per_shard)
         # Requests suspended at this level (awaiting re-route) are held by
         # no shard; shard-level "suspended" counts are always 0 here
         # because suspend() moves the records up immediately.
@@ -2250,7 +2268,7 @@ class ServeSupervisor:
             "work_units": self._vt(),
             "prefill_chunks": work.get("prefill_chunks", 0),
             "prefill_stall_steps": work.get("prefill_stall_steps", 0),
-            "first_tokens": len(ttft),
+            "ttft_samples": len(ttft),
             "ttft_units_p50": latency_percentile(ttft, 50),
             "ttft_units_p99": latency_percentile(ttft, 99),
             "ttft_steps_p50": latency_percentile(ttft_steps, 50),
