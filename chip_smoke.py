@@ -124,10 +124,11 @@ def check_live_pool(sess, cfg, seed: int) -> float:
     q = q.astype(jnp.bfloat16)
     got = ops.mla_decode_paged(
         q,
-        cache.layer_pages(layer),
+        cache.pages,
         jnp.asarray(bt),
         jnp.asarray(kv_len),
-        kv_scales=cache.layer_scales(layer),
+        kv_scales=cache.scales,
+        layer=layer,
         d_v=dv,
         scale=mla_scale(cfg),
         block_k=sess.block_k,

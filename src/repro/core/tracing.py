@@ -6,7 +6,9 @@ the device's XLA-module events.  While a :class:`Tracer` is current (see
 :meth:`Tracer.active`) the span also adds to that tracer's totals for its
 name: how many closed, their seconds, and their self seconds -- the seconds
 not covered by spans opened inside them (``time.perf_counter``).  With no
-tracer current a span only opens the annotation.
+tracer current a span only opens the annotation.  ``count(name, n)`` adds
+to a counter of the current tracer in the same way (and does nothing with
+none current).
 
 Totals belong to whoever owns the tracer: a serving session makes its own
 current around each call into it, so spans opened deep in ``models`` or
@@ -28,15 +30,17 @@ _current: contextvars.ContextVar[Tracer | None] = contextvars.ContextVar(
 
 
 class Tracer:
-    """Per span name: count, seconds and self seconds.
+    """Per span name: count, seconds and self seconds; and counters.
 
-    ``names`` are reported by :meth:`stats` even before any such span
-    closes, so the key set is stable and totals of several tracers sum."""
+    ``names`` (spans) and ``counters`` are reported by :meth:`stats` even
+    before any such span closes or count is added, so the key set is
+    stable and totals of several tracers sum."""
 
-    def __init__(self, names=()):
+    def __init__(self, names=(), counters=()):
         self.count = dict.fromkeys(names, 0)
         self.seconds = dict.fromkeys(names, 0.0)
         self.self_seconds = dict.fromkeys(names, 0.0)
+        self.counters = dict.fromkeys(counters, 0)
         self._open: list[float] = []  # child seconds of each open span
 
     @contextlib.contextmanager
@@ -58,13 +62,21 @@ class Tracer:
 
     def stats(self) -> dict:
         """Flat keys that sum: ``span_n.<name>``, ``span_s.<name>`` and
-        ``span_self_s.<name>``."""
+        ``span_self_s.<name>`` per span, and each counter by its name."""
         out = {}
         for name in self.count:
             out[f"span_n.{name}"] = self.count[name]
             out[f"span_s.{name}"] = self.seconds[name]
             out[f"span_self_s.{name}"] = self.self_seconds[name]
+        out.update(self.counters)
         return out
+
+
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to the current tracer's counter ``name``."""
+    tracer = _current.get()
+    if tracer is not None:
+        tracer.counters[name] = tracer.counters.get(name, 0) + n
 
 
 class span:

@@ -139,6 +139,7 @@ def combine_split_partials(
     return out[:, :g]
 
 
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def combine_hetero_partials(
     o_parts: list[jax.Array],  # each (Di, G, Dv) f32 normalized partials
     lse_parts: list[jax.Array],  # each (Di, G, 1) f32
@@ -157,7 +158,8 @@ def combine_hetero_partials(
     concatenated layout (``decode_schedule.PrefixSchedule
     .hetero_dest_tables`` builds exactly these tables).  Slot counts per
     request may be ragged; padding columns must repeat a live slot (warm
-    gated-off fetches) as in the homogeneous case.
+    gated-off fetches) as in the homogeneous case.  The concatenation and
+    the merge are one program.
     """
     o_all = o_parts[0] if len(o_parts) == 1 else jnp.concatenate(o_parts, 0)
     lse_all = (

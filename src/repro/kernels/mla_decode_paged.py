@@ -170,6 +170,32 @@ def clamp_tail_pages(
     return jnp.clip(bt, 0, num_pages - 1)
 
 
+def stacked_layer_view(kv_pages, kv_scales, block_tables, layer):
+    """Address layer ``layer`` of a layered ``(L, P, page, Dk)`` pool
+    without copying it.
+
+    The pool is viewed as ``(L*P, page, Dk)`` pages (merging leading axes
+    is a bitcast, not a copy) and the block table is offset by
+    ``layer * P``, so its page ids name that layer's pages; the scale pool
+    gets the same view.  A one-layer ``(P, page, Dk)`` pool passes through
+    unchanged (``layer`` must then be None).
+    """
+    if kv_pages.ndim == 3:
+        if layer is not None:
+            raise ValueError("layer given for a one-layer (P, page, Dk) pool")
+        return kv_pages, kv_scales, block_tables
+    if layer is None:
+        raise ValueError(
+            f"a layered {kv_pages.shape} pool needs the layer to attend"
+        )
+    n_layers, num_pages = kv_pages.shape[:2]
+    kv_pages = kv_pages.reshape((n_layers * num_pages,) + kv_pages.shape[2:])
+    if kv_scales is not None:
+        kv_scales = kv_scales.reshape(n_layers * num_pages, kv_scales.shape[-1])
+    block_tables = block_tables.astype(jnp.int32) + layer * num_pages
+    return kv_pages, kv_scales, block_tables
+
+
 # --------------------------------------------------------------------------- #
 # padded (B, W) grid kernel — baseline
 # --------------------------------------------------------------------------- #
@@ -522,6 +548,7 @@ def mla_decode_paged_queue_rows(
     item_last: jax.Array,  # (N,) int32 │
     item_valid: jax.Array,  # (N,) int32 ┘
     kv_scales: jax.Array | None = None,  # (P, SW) f32, int8 pools
+    layer: jax.Array | None = None,  # int32 layer of an (L, P, ...) pool
     *,
     d_v: int = 512,
     variant: str = "amla",
@@ -547,8 +574,17 @@ def mla_decode_paged_queue_rows(
     DMA-overlap window, and folds the scales into the PV probabilities —
     the AMLA state machine sees the same fp32 state either way, while the
     page DMAs move roughly half the bytes.
+
+    ``kv_pages`` may also be a layered cache's whole ``(L, P, page_size,
+    Dk)`` pool (and ``kv_scales`` its ``(L, P, SW)`` scales), with
+    ``layer`` the layer to attend: see :func:`stacked_layer_view`.  ``q``
+    may carry more rows than ``block_tables``; rows no work item names are
+    never read.
     """
-    b, g, d_k = q.shape
+    kv_pages, kv_scales, block_tables = stacked_layer_view(
+        kv_pages, kv_scales, block_tables, layer
+    )
+    g, d_k = q.shape[1:]
     num_pages, page_size, _ = kv_pages.shape
     if block_k % page_size or block_k < page_size:
         raise ValueError(
@@ -666,6 +702,18 @@ def mla_decode_paged_queue_rows(
 # --------------------------------------------------------------------------- #
 
 
+@functools.partial(
+    jax.jit,
+    static_argnames=(
+        "d_v",
+        "variant",
+        "scale",
+        "block_k",
+        "num_dest_slots",
+        "softcap",
+        "interpret",
+    ),
+)
 def mla_decode_paged_group_prefix(
     q: jax.Array,  # (B, G, Dk) per-request query rows
     kv_pages: jax.Array,  # (P, page_size, Dk) page pool, Dk lane-padded
@@ -681,6 +729,7 @@ def mla_decode_paged_group_prefix(
     item_last: jax.Array,  # │
     item_valid: jax.Array,  # ┘
     kv_scales: jax.Array | None = None,  # (P, SW) f32, int8 pools
+    layer: jax.Array | None = None,  # int32 layer of an (L, P, ...) pool
     *,
     d_v: int = 512,
     variant: str = "amla",
@@ -710,7 +759,8 @@ def mla_decode_paged_group_prefix(
     ready to concatenate after the suffix partial array for the combine
     kernel (see ``decode_schedule.PrefixSchedule.hetero_dest_tables``).
     Padded member rows finalize to zeros with ``lse == -inf`` and are never
-    referenced by a dest table.
+    referenced by a dest table.  A layered pool and its ``layer`` pass
+    through to the queue kernel (:func:`stacked_layer_view`).
     """
     b, g, d_k = q.shape
     n_groups, gmax = group_member.shape
@@ -736,6 +786,7 @@ def mla_decode_paged_group_prefix(
         item_last,
         item_valid,
         kv_scales,
+        layer,
         d_v=d_v,
         variant=variant,
         scale=scale,

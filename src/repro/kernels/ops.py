@@ -72,13 +72,26 @@ def default_paged_block_k(page_size: int, table_width: int) -> int:
     return page_size * min(pages_per_block, max(table_width, 1))
 
 
+def _host_lens(kv_len, site: str) -> np.ndarray:
+    """``kv_len`` as host numpy; reading a device array back is a
+    ``serve.sync`` span, host numpy costs nothing."""
+    if isinstance(kv_len, np.ndarray):
+        return kv_len.reshape(-1)
+    with span("serve.sync", site=site):
+        return np.asarray(kv_len).reshape(-1)
+
+
 def _validate_paged_geometry(
     q, kv_pages, block_tables, kv_len, block_k, kv_scales=None, scheduler="queue"
 ):
     """Fail fast, with actionable messages, on geometry the Pallas kernels
-    would otherwise reject deep inside a trace (or worse, read garbage)."""
+    would otherwise reject deep inside a trace (or worse, read garbage).
+
+    ``kv_pages`` is one pool ``(P, page, Dk)`` or a layered ``(L, P, page,
+    Dk)`` one (then ``kv_scales`` is ``(L, P, SW)``); only shapes and
+    dtypes are read, so shape structs do as well as arrays."""
     b = q.shape[0]
-    num_pages, page_size, dk_pages = kv_pages.shape
+    num_pages, page_size, dk_pages = kv_pages.shape[-3:]
     if kv_pages.dtype == jnp.int8:
         if kv_scales is None:
             raise ValueError(
@@ -99,7 +112,7 @@ def _validate_paged_geometry(
                 f"scale pools accompany int8 pools only"
             )
         want = (num_pages, _mla.lane_pad(page_size))
-        if tuple(kv_scales.shape) != want:
+        if tuple(kv_scales.shape[-2:]) != want:
             raise ValueError(
                 f"kv_scales must be {want} — one fp32 scale per page row, "
                 f"each page's row lane-padded (kv_cache.scale_pool_shape); "
@@ -132,11 +145,10 @@ def _validate_paged_geometry(
             f" or leave block_k=None for the §4.2 default)"
         )
     # Table-width bound: every valid token must resolve to a table entry.
-    # A device kv_len is read back to the host here (every call on the
-    # serving path); skip silently when traced.
+    # A device kv_len is read back to the host here; skip silently when
+    # traced.
     if not isinstance(kv_len, jax.core.Tracer):
-        with span("serve.sync", site="kv_len_check"):
-            lens = np.asarray(kv_len).reshape(-1)
+        lens = _host_lens(kv_len, "kv_len_check")
         if lens.size and int(lens.max()) > w * page_size:
             worst = int(np.argmax(lens))
             raise ValueError(
@@ -191,8 +203,7 @@ def _validate_q_positions(q_positions, b, sq, kv_len, scheduler, q_offset, causa
             f"has {arr[bad].tolist()}"
         )
     if not isinstance(kv_len, jax.core.Tracer):
-        with span("serve.sync", site="q_positions_check"):
-            lens = np.asarray(kv_len).reshape(-1)
+        lens = _host_lens(kv_len, "q_positions_check")
         over = arr >= lens[:, None]
         if np.any(over):
             bad = int(np.argmax(np.any(over, axis=1)))
@@ -204,6 +215,22 @@ def _validate_q_positions(q_positions, b, sq, kv_len, scheduler, q_offset, causa
             )
 
 
+def validate_paged_call(
+    q, kv_pages, block_tables, kv_len, *, block_k, kv_scales=None,
+    q_positions=None,
+):
+    """The checks :func:`mla_decode_paged` makes of its geometry (and of
+    ``q_positions``), for a caller that prepares the kernels' inputs
+    itself: ``q`` may be a shape struct, and host numpy ``kv_len`` needs
+    no read from the device."""
+    _validate_paged_geometry(
+        q, kv_pages, block_tables, kv_len, block_k, kv_scales
+    )
+    if q_positions is not None:
+        b, sq = q.shape[:2]
+        _validate_q_positions(q_positions, b, sq, kv_len, "queue", None, True)
+
+
 def mla_decode_paged(
     q: jax.Array,  # (B, Sq, Hq, Dk)
     kv_pages: jax.Array,  # kv_cache.pool_shape(P, page_size, Dk) page pool
@@ -211,6 +238,7 @@ def mla_decode_paged(
     kv_len: jax.Array,  # (B,) int32 valid tokens per request
     *,
     kv_scales: jax.Array | None = None,  # scale_pool_shape f32 (int8 pools)
+    layer: jax.Array | int | None = None,  # layer of an (L, P, ...) pool
     d_v: int = 512,
     variant: str = "amla",
     interpret: bool = False,
@@ -288,6 +316,10 @@ def mla_decode_paged(
     the combine kernel — so a request whose KV is partitioned across hosts
     can merge shard-local results exactly with
     :func:`repro.core.distributed.combine_shard_partials`.
+
+    ``kv_pages`` may be a ``LayeredPagedKVCache``'s whole ``(L, P, page,
+    Dk)`` pool with ``layer`` naming the layer to attend (queue scheduler
+    only): the kernels address it in place, no layer is sliced out.
     """
     b, sq, hq, dk = q.shape
     compute_dtype = jnp.bfloat16 if compute_dtype is None else compute_dtype
@@ -306,13 +338,15 @@ def mla_decode_paged(
     if q_positions is not None:
         q_pos = jnp.asarray(q_positions).astype(jnp.int32)
     if not causal:
-        cap = block_tables.shape[1] * kv_pages.shape[1]
+        cap = block_tables.shape[1] * kv_pages.shape[-2]
         q_pos = jnp.full((b, sq), cap, jnp.int32)  # no causal restriction
     rows_pos = jnp.repeat(q_pos, hq, axis=1)  # (B, Sq*Hq)
     q_rows = q.reshape(b, sq * hq, dk).astype(compute_dtype)
     # Pool rows are lane-padded; zero query lanes add nothing to the scores.
     q_rows = jnp.pad(q_rows, ((0, 0), (0, 0), (0, kv_pages.shape[-1] - dk)))
 
+    if isinstance(schedule, _sched.PrefixSchedule):
+        prefix_sharing = True
     if return_partials and (scheduler != "queue" or prefix_sharing):
         raise ValueError(
             "return_partials needs the plain queue scheduler (padded and "
@@ -326,10 +360,13 @@ def mla_decode_paged(
                 "(B, W) grid walks every request independently and cannot "
                 "batch shared-prefix groups)"
             )
+        pages, _, tables = _mla_paged.stacked_layer_view(
+            kv_pages, None, block_tables, layer
+        )
         out = _mla_paged.mla_decode_paged_rows(
             q_rows,
-            kv_pages,
-            block_tables,
+            pages,
+            tables,
             kv_len,
             rows_pos,
             d_v=d_v,
@@ -342,12 +379,12 @@ def mla_decode_paged(
     if scheduler != "queue":
         raise ValueError(f"unknown scheduler {scheduler!r}")
 
-    page_size = kv_pages.shape[1]
+    page_size = kv_pages.shape[-2]
     if block_k is None:
         block_k = default_paged_block_k(page_size, block_tables.shape[1])
-    if isinstance(schedule, _sched.PrefixSchedule):
-        prefix_sharing = True
-    elif schedule is not None and prefix_sharing:
+    if schedule is not None and prefix_sharing and not isinstance(
+        schedule, _sched.PrefixSchedule
+    ):
         raise ValueError(
             "prefix_sharing=True needs a decode_schedule.PrefixSchedule "
             f"(got {type(schedule).__name__}); build one with "
@@ -358,19 +395,12 @@ def mla_decode_paged(
             f"schedule was built for block_k={schedule.block_k}, "
             f"call requested {block_k}"
         )
-    if prefix_sharing:
-        if return_partials:
-            raise ValueError(
-                "return_partials needs the plain queue scheduler (padded "
-                "and prefix-sharing paths merge heterogeneous partial sets "
-                "and do not expose a per-row lse)"
-            )
-        ps = schedule
-        if ps is None:
-            with span("serve.sync", site="schedule"):
-                kv_host = np.asarray(kv_len)
-                bt_host = np.asarray(block_tables)
-            ps = _sched.build_prefix_schedule(
+    if schedule is None:
+        with span("serve.sync", site="schedule"):
+            kv_host = np.asarray(kv_len)
+            bt_host = np.asarray(block_tables) if prefix_sharing else None
+        if prefix_sharing:
+            schedule = _sched.build_prefix_schedule(
                 kv_host,
                 bt_host,
                 page_size=page_size,
@@ -378,88 +408,33 @@ def mla_decode_paged(
                 num_splits=num_splits,
                 min_group=min_group,
             )
-        o_suf, lse_suf = _mla_paged.mla_decode_paged_queue_rows(
-            q_rows,
-            kv_pages,
-            block_tables,
-            kv_len,
-            rows_pos,
-            *map(jnp.asarray, ps.suffix.prefetch_arrays()),
-            kv_scales,
-            d_v=d_v,
-            variant=variant,
-            scale=scale,
-            block_k=block_k,
-            num_dest_slots=ps.suffix.num_dest_slots,
-            softcap=softcap,
-            interpret=interpret,
-        )
-        o_parts, lse_parts = [o_suf], [lse_suf]
-        if ps.num_groups:
-            o_pref, lse_pref = _mla_paged.mla_decode_paged_group_prefix(
-                q_rows,
-                kv_pages,
-                block_tables,
-                rows_pos,
-                jnp.asarray(ps.groups.group_member),
-                jnp.asarray(ps.groups.group_rep),
-                jnp.asarray(ps.prefix_lens, dtype=jnp.int32),
-                *map(jnp.asarray, ps.prefix.prefetch_arrays()),
-                kv_scales,
-                d_v=d_v,
-                variant=variant,
-                scale=scale,
-                block_k=block_k,
-                num_dest_slots=ps.prefix.num_dest_slots,
-                softcap=softcap,
-                interpret=interpret,
+        else:
+            schedule = _sched.build_schedule(
+                kv_host, block_k=block_k, num_splits=num_splits
             )
-            o_parts.append(o_pref)
-            lse_parts.append(lse_pref)
-        dest, n_live = ps.hetero_dest_tables()
-        out = _combine.combine_hetero_partials(
-            o_parts,
-            lse_parts,
-            jnp.asarray(dest),
-            jnp.asarray(n_live),
-            interpret=interpret,
-        )
-        return out.reshape(b, sq, hq, d_v)
-
-    if schedule is None:
-        with span("serve.sync", site="schedule"):
-            kv_host = np.asarray(kv_len)
-        schedule = _sched.build_schedule(
-            kv_host, block_k=block_k, num_splits=num_splits
-        )
-    o_part, lse = _mla_paged.mla_decode_paged_queue_rows(
+    arrays = jax.tree.map(jnp.asarray, schedule_arrays(schedule))
+    out, lse = queue_attention(
         q_rows,
+        rows_pos,
         kv_pages,
         block_tables,
         kv_len,
-        rows_pos,
-        *map(jnp.asarray, schedule.prefetch_arrays()),
-        kv_scales,
+        schedule,
+        arrays,
+        layer=None if layer is None else jnp.asarray(layer, jnp.int32),
+        kv_scales=kv_scales,
         d_v=d_v,
         variant=variant,
         scale=scale,
         block_k=block_k,
-        num_dest_slots=schedule.num_dest_slots,
         softcap=softcap,
-        interpret=interpret,
-    )
-    out = _combine.combine_split_partials(
-        o_part,
-        lse,
-        jnp.asarray(schedule.dest_table),
-        jnp.asarray(schedule.n_splits),
         interpret=interpret,
     )
     if return_partials:
         # Per-row local logsumexp across this call's split slots: dead slots
         # carry BIG_NEG lse, so including them adds exp(BIG_NEG) = 0.
         lse_slots = lse[..., 0] if lse.ndim == 3 else lse  # (D, G)
-        per_split = lse_slots[jnp.asarray(schedule.dest_table)]  # (B, S, G)
+        per_split = lse_slots[arrays["dest"]]  # (B, S, G)
         lse_rows = jax.nn.logsumexp(
             per_split.astype(jnp.float32), axis=1
         )  # (B, G)
@@ -468,6 +443,91 @@ def mla_decode_paged(
             lse_rows.reshape(b, sq, hq),
         )
     return out.reshape(b, sq, hq, d_v)
+
+
+def schedule_arrays(schedule, rows: int | None = None) -> dict:
+    """A decode schedule's kernel-side int32 arrays, as host numpy.
+
+    ``suffix``: the six arrays of the queue kernel's work queue (the whole
+    queue of a :class:`~repro.kernels.decode_schedule.DecodeSchedule`, the
+    per-request suffix of a ``PrefixSchedule``); with prefix groups also
+    ``prefix`` (the group-prefix queue), ``group_member``, ``group_rep``
+    and ``prefix_lens``; then the combine's ``dest`` table and ``n_live``
+    partials per request.  ``rows`` pads the combine's tables to that many
+    requests: a padded request has no live partial and comes out as zeros.
+    """
+    if isinstance(schedule, _sched.PrefixSchedule):
+        dest, n_live = schedule.hetero_dest_tables()
+        out = {"suffix": schedule.suffix.prefetch_arrays()}
+        if schedule.num_groups:
+            out["prefix"] = schedule.prefix.prefetch_arrays()
+            out["group_member"] = schedule.groups.group_member
+            out["group_rep"] = schedule.groups.group_rep
+            out["prefix_lens"] = np.asarray(schedule.prefix_lens, np.int32)
+    else:
+        dest, n_live = schedule.dest_table, schedule.n_splits
+        out = {"suffix": schedule.prefetch_arrays()}
+    pad = 0 if rows is None else rows - dest.shape[0]
+    out["dest"] = np.pad(dest, ((0, pad), (0, 0)))
+    out["n_live"] = np.pad(n_live, (0, pad))
+    return out
+
+
+def queue_attention(
+    q_rows: jax.Array,  # (B', G, Dk_pool) query rows, B' >= B
+    rows_pos: jax.Array,  # (B', G) int32 absolute position per row
+    kv_pages: jax.Array,  # (P, page, Dk_pool) or (L, P, page, Dk_pool)
+    block_tables: jax.Array,  # (B, W) int32
+    kv_len: jax.Array,  # (B,) int32
+    schedule,  # the DecodeSchedule or PrefixSchedule ``arrays`` came from
+    arrays: dict,  # schedule_arrays(schedule, rows) on the device
+    *,
+    layer: jax.Array | None = None,
+    kv_scales: jax.Array | None = None,
+    d_v: int,
+    variant: str,
+    scale: float,
+    block_k: int,
+    softcap: float | None = None,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """The kernels of one paged attention call over prepared query rows.
+
+    The queue kernel runs the schedule's (suffix) queue; a
+    ``PrefixSchedule`` with groups adds the group-prefix kernel; the
+    combine merges the partials into ``(rows of arrays["dest"], G, Dv)``.
+    Returns that and the queue kernel's per-slot lse.  Two or three device
+    programs, nothing else: every input is already on the device.
+    """
+    if isinstance(schedule, _sched.PrefixSchedule):
+        suffix, prefix = schedule.suffix, schedule.prefix
+    else:
+        suffix, prefix = schedule, None
+    kw = dict(
+        d_v=d_v, variant=variant, scale=scale, block_k=block_k,
+        softcap=softcap, interpret=interpret,
+    )
+    o, lse = _mla_paged.mla_decode_paged_queue_rows(
+        q_rows, kv_pages, block_tables, kv_len, rows_pos,
+        *arrays["suffix"], kv_scales, layer,
+        num_dest_slots=suffix.num_dest_slots, **kw,
+    )
+    if "prefix" in arrays:
+        o_pref, lse_pref = _mla_paged.mla_decode_paged_group_prefix(
+            q_rows, kv_pages, block_tables, rows_pos,
+            arrays["group_member"], arrays["group_rep"],
+            arrays["prefix_lens"], *arrays["prefix"], kv_scales, layer,
+            num_dest_slots=prefix.num_dest_slots, **kw,
+        )
+        out = _combine.combine_hetero_partials(
+            [o, o_pref], [lse, lse_pref], arrays["dest"], arrays["n_live"],
+            interpret=interpret,
+        )
+    else:
+        out = _combine.combine_split_partials(
+            o, lse, arrays["dest"], arrays["n_live"], interpret=interpret
+        )
+    return out, lse
 
 
 def gqa_attention(

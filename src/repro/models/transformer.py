@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.tracing import span
+from repro.core.tracing import count, span
 from repro.models import layers, moe
 from repro.models.attention_layer import gqa_apply, gqa_init, init_kv_cache
 from repro.models.mla_layer import (
@@ -38,6 +38,7 @@ from repro.models.recurrent import (
     rglru_block_init,
 )
 from repro.models.ssm import init_ssm_cache, mamba2_block_apply, mamba2_block_init
+from repro.runtime.kv_cache import OutOfPagesError, plan_rows, scatter_layer_rows
 
 
 def _has_mlp(cfg, kind):
@@ -293,11 +294,13 @@ def lm_logits(params, hidden, *, cfg, dtype=jnp.bfloat16):
 # The dense path above threads a (B, max_len) cache pytree through lm_apply;
 # the paged path instead walks the layer stack host-side so each layer can
 # (1) append its 576-wide latent row(s) into the shared page pool and
-# (2) attend through ops.mla_decode_paged with ONE decode schedule built per
-# step and reused by every layer — all L layers share the same block table
+# (2) attend through the paged kernels with ONE decode schedule built per
+# pass and reused by every layer — all L layers share the same block table
 # and kv_len, so the (request, kv_block) work queue is identical for each.
-# Per-layer math is jitted (cfg is static: a frozen dataclass); only page
-# bookkeeping and kernel dispatch run eagerly.
+# A pass uploads its inputs once; per layer it launches one program up to
+# the kernels (the previous layer's post-attention half fused with this
+# layer's inputs and cache write), the kernels and the combine, all on the
+# stacked (L, P, page, 640) pool.  cfg is static (a frozen dataclass).
 
 
 def check_paged_compatible(cfg) -> None:
@@ -342,7 +345,6 @@ def _cfg_dtype(cfg):
     return jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
 def _paged_embed(embed_params, tokens, *, cfg):
     x = layers.embed(embed_params, tokens, dtype=_cfg_dtype(cfg))
     if getattr(cfg, "embed_scale", False):
@@ -350,7 +352,6 @@ def _paged_embed(embed_params, tokens, *, cfg):
     return x
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
 def _paged_attn_inputs(p_l, x, positions, *, cfg):
     """Pre-attention half of one layer: latent rows + absorbed queries."""
     dtype = _cfg_dtype(cfg)
@@ -362,10 +363,15 @@ def _paged_attn_inputs(p_l, x, positions, *, cfg):
     return lat, q
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
 def _paged_layer_post(p_l, x, attn, *, cfg):
-    """Post-attention half: un-absorb, residual, MLP (mirrors layer_apply)."""
+    """Post-attention half: un-absorb, residual, MLP (mirrors layer_apply).
+
+    ``attn`` holds the combine's ``(B, S*h, Dv)`` output of each head
+    chunk, in head order."""
     dtype = _cfg_dtype(cfg)
+    s = x.shape[1]
+    parts = [a.reshape(a.shape[0], s, -1, a.shape[-1]) for a in attn]
+    attn = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=2)
     y = mla_unabsorb_output(p_l["attn"], attn.astype(dtype), cfg=cfg, dtype=dtype)
     if cfg.post_norms:
         y = layers.rmsnorm(p_l["post_ln1"], y, eps=cfg.norm_eps)
@@ -382,71 +388,147 @@ def _paged_layer_post(p_l, x, attn, *, cfg):
     return x
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _paged_logits_at(params, x, idx, *, cfg):
-    """Final norm + unembedding of one position per batch row (dynamic
-    ``idx`` so ragged tail chunks don't retrace)."""
-    x = layers.rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
-    sel = jax.lax.dynamic_slice_in_dim(x, idx, 1, axis=1)
-    return lm_logits(params, sel, cfg=cfg, dtype=_cfg_dtype(cfg))
+def _query_rows(q, positions, *, lanes, compute_dtype, head_shards):
+    """The queue kernel's query rows: per head chunk ``(B, S*h, lanes)`` in
+    the compute dtype (bf16 unless given), zero lanes past Dk (they add
+    nothing to the scores); and each row's absolute position ``(B, S*h)``,
+    which every head of a token shares.  A row attends causally up to its
+    own position: the token's own latent is in the cache before the
+    kernel runs, so a 1-row decode step's position is ``kv_len - 1``."""
+    b, s, hq, dk = q.shape
+    h = hq // head_shards
+    dtype = jnp.bfloat16 if compute_dtype is None else compute_dtype
+    parts = tuple(
+        jnp.pad(
+            q[:, :, i * h : (i + 1) * h].reshape(b, s * h, dk).astype(dtype),
+            ((0, 0), (0, 0), (0, lanes - dk)),
+        )
+        for i in range(head_shards)
+    )
+    return parts, jnp.repeat(positions, h, axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _paged_logits_all(params, x, *, cfg):
-    """Final norm + unembedding at **every** position: the k-row verify
-    step of speculative decode needs logits for all k rows at once (row j
-    both scores draft j+1 and supplies the bonus token on rejection)."""
-    x = layers.rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
-    return lm_logits(params, x, cfg=cfg, dtype=_cfg_dtype(cfg))
-
-
-def _paged_attend(
-    q, cache, layer, bt, kv_len, *, cfg, block_k, schedule, q_offset,
-    num_splits, interpret, compute_dtype, variant, head_shards: int = 1,
-    q_positions=None,
+@functools.partial(
+    jax.jit,
+    static_argnames=("cfg", "compute_dtype", "head_shards"),
+    donate_argnames=("pages", "scales"),
+)
+def _paged_layer_in(
+    embed, p_post, p_l, x, attn, dense, pages, scales, layer, *, cfg,
+    compute_dtype, head_shards,
 ):
+    """One program for everything of a layer up to its attention kernels.
+
+    The first layer embeds the pass's tokens (``embed``); a later one runs
+    the previous layer's post-attention half (``p_post``) on the residual
+    ``x`` and that layer's kernel output ``attn``.  Then this layer's
+    latent rows and absorbed queries, the latent write into its plane of
+    the donated layered pool (``layer``; int8 pools quantize into their
+    scale pool), and the kernels' query rows and positions.  ``dense``
+    stacks the pass's ``(B, S)`` int32 tokens, positions and per-row write
+    pages and offsets.  Optimization barriers after the residual and after
+    the attention inputs keep XLA from fusing across them, so their values
+    are rounded to their dtype there whatever else the program holds (the
+    exact-parity tests compare against unfused forward passes).
+    Returns ``(x, query row chunks, row positions, pages, scales)``.
+    """
+    tokens, positions, pids, offs = dense
+    if p_post is None:
+        x = _paged_embed(embed, tokens, cfg=cfg)
+    else:
+        x = _paged_layer_post(p_post, x, attn, cfg=cfg)
+    x = jax.lax.optimization_barrier(x)
+    lat, q = jax.lax.optimization_barrier(
+        _paged_attn_inputs(p_l, x, positions, cfg=cfg)
+    )
+    pages, scales = scatter_layer_rows(
+        pages, scales, lat.reshape(-1, lat.shape[-1]), layer,
+        pids.reshape(-1), offs.reshape(-1),
+    )
+    q_rows, rows_pos = _query_rows(
+        q, positions, lanes=pages.shape[-1], compute_dtype=compute_dtype,
+        head_shards=head_shards,
+    )
+    return x, q_rows, rows_pos, pages, scales
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _paged_head(params, p_post, x, attn, idx, *, cfg):
+    """The last layer's post-attention half, the final norm and the
+    unembedding, in one program: logits ``(B, S, vocab)`` at every row, or
+    ``(B, vocab)`` at row ``idx`` of each sequence (a traced index, so a
+    ragged tail chunk does not retrace)."""
+    x = jax.lax.optimization_barrier(_paged_layer_post(p_post, x, attn, cfg=cfg))
+    x = layers.rmsnorm(params["final_norm"], x, eps=cfg.norm_eps)
+    if idx is not None:
+        x = jax.lax.dynamic_slice_in_dim(x, idx, 1, axis=1)
+    logits = lm_logits(params, x, cfg=cfg, dtype=_cfg_dtype(cfg))
+    return logits if idx is None else logits[:, 0]
+
+
+def _paged_pass(
+    params, layers_p, cache, host, schedule, *, cfg, block_k, variant,
+    interpret, compute_dtype, head_shards,
+):
+    """One pass of the layer walk: a decode step, or one prefill chunk.
+
+    ``host`` holds every array the pass needs, as host numpy: ``dense``
+    (:func:`_paged_layer_in`), the block table ``bt`` and ``kv_len`` of the
+    live requests, the schedule's ``ops.schedule_arrays`` under ``sched``
+    and, for a prefill, the logits row ``idx``.  They go to the pool's
+    device in one transfer; the geometry and positions were checked on the
+    host, so nothing is read back.  Per layer the walk then launches
+    :func:`_paged_layer_in` and, per head chunk, the queue kernel (plus the
+    group-prefix kernel under a prefix schedule) and the combine, on the
+    stacked pool with no per-layer copy.  Returns the device arrays and
+    what :func:`_paged_head` needs: the last layer's params, ``x`` and
+    ``attn``.  The programs launched count as ``walk_dispatches``.
+    """
     from repro.kernels import ops
 
-    def attend(q_part):
-        return ops.mla_decode_paged(
-            q_part,
-            cache.layer_pages(layer),
-            bt,
-            kv_len,
-            # int8 pools carry per-row dequant scales; the queue kernel
-            # fuses the dequant into its preload pipeline (see kernels.ops).
-            kv_scales=cache.layer_scales(layer),
-            d_v=cfg.mla.d_latent,
-            variant=variant,
-            scale=mla_scale(cfg),
-            interpret=interpret,
-            q_offset=q_offset,
-            q_positions=q_positions,
-            scheduler="queue",
-            block_k=block_k,
-            num_splits=num_splits,
-            schedule=schedule,
-            compute_dtype=compute_dtype,
+    dev = jax.device_put(host, cache.device)
+    layer_ids = cache.layer_ids()
+    kw = dict(
+        d_v=cfg.mla.d_latent, variant=variant, scale=mla_scale(cfg),
+        block_k=block_k, interpret=interpret,
+    )
+    kernels_per_chunk = 3 if "prefix" in dev["sched"] else 2
+    p_post = x = attn = None
+    for l, p_l in enumerate(layers_p):
+        x, q_rows, rows_pos, cache.pages, cache.scales = _paged_layer_in(
+            params["embed"] if p_post is None else None, p_post, p_l, x,
+            attn, dev["dense"], cache.pages, cache.scales, layer_ids[l],
+            cfg=cfg, compute_dtype=compute_dtype, head_shards=head_shards,
         )
+        attn = tuple(
+            ops.queue_attention(
+                q, rows_pos, cache.pages, dev["bt"], dev["kv_len"], schedule,
+                dev["sched"], layer=layer_ids[l], kv_scales=cache.scales,
+                **kw,
+            )[0]
+            for q in q_rows
+        )
+        count("walk_dispatches", 1 + kernels_per_chunk * len(q_rows))
+        p_post = p_l
+    return dev, p_post, x, attn
 
-    if head_shards <= 1:
-        return attend(q)
-    # Tensor-parallel head groups (the mesh's ``model`` axis): query heads
-    # are independent in the queue kernel (per-row softmax over the same
-    # pages), so chunking the head axis is bitwise exact and every chunk
-    # reuses the same per-(request, block) schedule.  On one process this
-    # runs the chunks sequentially; a multi-controller deployment runs one
-    # chunk per model-axis device against its pool replica.
-    hq = q.shape[2]
-    if hq % head_shards:
+
+def _check_pass(cfg, cache, bt, kv_len, block_k, head_shards, s, positions=None):
+    """Host-side checks of a pass's attention geometry (and, for a
+    multi-row step, its query positions) -- once per pass, on host numpy."""
+    from repro.kernels import ops
+
+    if cfg.n_heads % head_shards:
         raise ValueError(
-            f"head_shards={head_shards} must divide n_heads={hq}"
+            f"head_shards={head_shards} must divide n_heads={cfg.n_heads}"
         )
-    step = hq // head_shards
-    parts = [
-        attend(q[:, :, i * step : (i + 1) * step]) for i in range(head_shards)
-    ]
-    return jnp.concatenate(parts, axis=2)
+    q_like = jax.ShapeDtypeStruct(
+        (bt.shape[0], s, cfg.n_heads // head_shards, cache.width), jnp.bfloat16
+    )
+    ops.validate_paged_call(
+        q_like, cache.pages, bt, kv_len, block_k=block_k,
+        kv_scales=cache.scales, q_positions=positions,
+    )
 
 
 def lm_prefill_paged(
@@ -471,19 +553,21 @@ def lm_prefill_paged(
 
     The prompt is processed in fixed-size chunks of ``chunk`` tokens (the
     tail chunk is zero-padded, so every chunk compiles to one shape): each
-    chunk's latents are appended into ``rid``'s pages layer by layer, and
-    each layer attends its ``chunk * H`` query rows over the request's pages
-    with per-row causal positions — which is also what makes a forked
-    request work: ``start_pos > 0`` (an ``admit_with_prefix`` suffix) scores
-    the new rows against the aliased prefix pages it never re-computes.
+    chunk is one pass of the layer walk (:func:`_paged_pass`), which writes
+    its latents into ``rid``'s pages layer by layer and attends each
+    layer's ``chunk * H`` query rows over the request's pages with per-row
+    causal positions — which is also what makes a forked request work:
+    ``start_pos > 0`` (an ``admit_with_prefix`` suffix) scores the new rows
+    against the aliased prefix pages it never re-computes.
 
-    Only the final chunk unembeds (the earlier chunks' logits were never
-    returned anyway).  ``need_logits=False`` skips even that and returns
-    None — the budgeted-interleaving path uses it for intermediate slices
-    of a prompt whose first-token logits come from a *later* call: cache
-    rows are written identically either way, so a prompt prefilled in
-    several ``start_pos``-advancing chunk-aligned calls is bit-identical
-    to one monolithic call.
+    Only the final chunk runs the last layer's post-attention half and the
+    unembedding (an earlier chunk's output past its cache writes is never
+    used).  ``need_logits=False`` skips even that and returns None — the
+    budgeted-interleaving path uses it for intermediate slices of a prompt
+    whose first-token logits come from a *later* call: cache rows are
+    written identically either way, so a prompt prefilled in several
+    ``start_pos``-advancing chunk-aligned calls is bit-identical to one
+    monolithic call.
     """
     from repro.kernels import decode_schedule as _sched
     from repro.kernels import ops
@@ -502,37 +586,32 @@ def lm_prefill_paged(
     logits = None
     for s0 in range(0, s_total, chunk):
         valid = min(chunk, s_total - s0)
-        tok = np.zeros((1, chunk), np.int32)
-        tok[0, :valid] = tokens[s0 : s0 + valid]
         abs0 = start_pos + s0
-        positions = jnp.asarray(abs0 + np.arange(chunk, dtype=np.int32))[None]
         with span("serve.schedule"):
             plan = cache.reserve(rid, valid)
             bt, kv_len = cache.block_table([rid], width=tw)
             # One schedule per chunk, shared by all L layers (same block
-            # table and kv_len everywhere) — kv_len is host numpy here, so
-            # this costs no device sync.
+            # table and kv_len everywhere); kv_len is host numpy.
             schedule = _sched.build_schedule(
                 kv_len, block_k=block_k, num_splits=1
             )
-        bt, kv_len = jnp.asarray(bt), jnp.asarray(kv_len)
-        q_off = jnp.full((1,), abs0, jnp.int32)
-        x = _paged_embed(params["embed"], jnp.asarray(tok), cfg=cfg)
-        for l, p_l in enumerate(layers_p):
-            lat, q = _paged_attn_inputs(p_l, x, positions, cfg=cfg)
-            cache.write_layer(l, plan, lat[0, :valid])
-            attn = _paged_attend(
-                q, cache, l, bt, kv_len, cfg=cfg, block_k=block_k,
-                schedule=schedule, q_offset=q_off, num_splits=1,
-                interpret=interpret, compute_dtype=compute_dtype,
-                variant=variant, head_shards=head_shards,
-            )
-            x = _paged_layer_post(p_l, x, attn, cfg=cfg)
+        _check_pass(cfg, cache, bt, kv_len, block_k, head_shards, chunk)
+        dense = np.zeros((4, 1, chunk), np.int32)
+        dense[0, 0, :valid] = tokens[s0 : s0 + valid]
+        dense[1, 0] = abs0 + np.arange(chunk, dtype=np.int32)
+        dense[2:, 0] = plan_rows([plan], chunk, cache.num_pages)
+        host = {"dense": dense, "bt": bt, "kv_len": kv_len,
+                "sched": ops.schedule_arrays(schedule),
+                "idx": np.int32(valid - 1)}
+        dev, p_last, x, attn = _paged_pass(
+            params, layers_p, cache, host, schedule, cfg=cfg,
+            block_k=block_k, variant=variant, interpret=interpret,
+            compute_dtype=compute_dtype, head_shards=head_shards,
+        )
         if need_logits and s0 + chunk >= s_total:
-            logits = _paged_logits_at(
-                params, x, jnp.int32(valid - 1), cfg=cfg
-            )
-    return logits[:, 0] if need_logits else None
+            logits = _paged_head(params, p_last, x, attn, dev["idx"], cfg=cfg)
+            count("walk_dispatches")
+    return logits
 
 
 def lm_decode_step_paged(
@@ -555,24 +634,24 @@ def lm_decode_step_paged(
     head_shards: int = 1,
     batch_rows: int | None = None,
 ) -> jax.Array:
-    """One paged full-model decode step; returns logits ``(B, S, vocab)``.
+    """One paged full-model decode step; returns logits ``(B', S, vocab)``:
+    ``B' = B`` live rows, or the padded batch under ``batch_rows``.
 
     Appends are atomic (OutOfPagesError raised before any page is claimed),
-    then each layer appends its latent row(s) and attends.  The decode
-    schedule is built **once per step** — every layer shares the block
-    table and kv_len, so one (request, kv_block) work queue serves all L
-    attention calls (pass ``scheduler`` to also memoize it across steps;
-    its hit/rebuild counters then count steps, not layers — the
-    scheduler-stats acceptance check).
+    then one pass of the layer walk (:func:`_paged_pass`) appends each
+    layer's latent row(s) and attends.  The decode schedule is built **once
+    per step** — every layer shares the block table and kv_len, so one
+    (request, kv_block) work queue serves all L attention calls (pass
+    ``scheduler`` to also memoize it across steps; its hit/rebuild counters
+    then count steps, not layers — the scheduler-stats acceptance check).
 
     ``S > 1`` is the speculative **verify** step: all S rows (the pending
     token plus S-1 draft tokens) append, then attend in one fused call with
-    explicit per-row positions (``ops.mla_decode_paged(q_positions=...)``)
-    — the same page DMAs feed S query rows, which is the whole point.
-    Causal masking makes the batched forward exactly the sequential one, so
-    row j's logits are valid whenever drafts 1..j matched greedy; the
-    caller accepts the longest matching prefix and rolls the cache back
-    with ``LayeredPagedKVCache.truncate``.
+    explicit per-row positions — the same page DMAs feed S query rows,
+    which is the whole point.  Causal masking makes the batched forward
+    exactly the sequential one, so row j's logits are valid whenever drafts
+    1..j matched greedy; the caller accepts the longest matching prefix and
+    rolls the cache back with ``LayeredPagedKVCache.truncate``.
 
     ``batch_rows`` fixes the batch the dense per-layer programs run on: the
     B live rows are padded to a multiple of it (pad rows are token 0 at
@@ -582,10 +661,11 @@ def lm_decode_step_paged(
     others share its step only while the padded batch stays the same: up to
     ``batch_rows`` live requests, every step and every session with the same
     ``batch_rows`` runs one program.  None runs the live batch as it is.
+    The logits keep the pad rows after the B live ones, so no program
+    slices them off; a caller reads the first B.
     """
     from repro.kernels import decode_schedule as _sched
     from repro.kernels import ops
-    from repro.runtime.kv_cache import OutOfPagesError
 
     check_paged_compatible(cfg)
     if len(rids) == 0:
@@ -616,17 +696,6 @@ def lm_decode_step_paged(
                 f"and retry"
             )
         plans = [cache.reserve(r, s) for r in rids]
-        # Flatten each request's reserve chunks to one (page, offset) per
-        # row: a speculative run of S rows may straddle a page boundary
-        # mid-request, so the scatter write needs per-row destinations.
-        pids = np.empty((len(rids) * s,), np.int32)
-        offs = np.empty((len(rids) * s,), np.int32)
-        w = 0
-        for plan in plans:
-            for pid, off0, m in plan:
-                pids[w : w + m] = pid
-                offs[w : w + m] = off0 + np.arange(m, dtype=np.int32)
-                w += m
         bt, kv_len = cache.block_table(rids, width=tw)
 
         # One schedule per step, shared by all L layers (they see the same
@@ -650,33 +719,26 @@ def lm_decode_step_paged(
                 kv_len, block_k=block_k, num_splits=num_splits
             )
 
-    bt, kv_len = jnp.asarray(bt), jnp.asarray(kv_len)
+    _check_pass(
+        cfg, cache, bt, kv_len, block_k, head_shards, s,
+        positions=positions if s > 1 else None,
+    )
     b = len(rids)
     b_pad = b if batch_rows is None else -(-b // batch_rows) * batch_rows
-    tok = np.zeros((b_pad, s), np.int32)
-    tok[:b] = tokens
-    pos = np.zeros((b_pad, s), np.int32)
-    pos[:b] = positions
-    x = _paged_embed(params["embed"], jnp.asarray(tok), cfg=cfg)
-    pos = jnp.asarray(pos)
-    # S == 1 keeps the derived-position path (bit-identical traces to the
-    # pre-speculation step); S > 1 passes the rows' absolute positions
-    # through the explicit multi-row surface.
-    q_positions = positions if s > 1 else None
-    for l, p_l in enumerate(layers_p):
-        lat, q = _paged_attn_inputs(p_l, x, pos, cfg=cfg)
-        # Rows past the b*s live ones (the pad rows) are not written.
-        cache.write_layer_tokens(l, pids, offs, lat.reshape(b_pad * s, -1))
-        attn = _paged_attend(
-            q[:b], cache, l, bt, kv_len, cfg=cfg, block_k=block_k,
-            schedule=schedule, q_offset=None, q_positions=q_positions,
-            num_splits=num_splits, interpret=interpret,
-            compute_dtype=compute_dtype, variant=variant,
-            head_shards=head_shards,
-        )
-        if b_pad != b:
-            attn = jnp.pad(attn, ((0, b_pad - b),) + ((0, 0),) * (attn.ndim - 1))
-        x = _paged_layer_post(p_l, x, attn, cfg=cfg)
-    if s == 1:
-        return _paged_logits_at(params, x, jnp.int32(0), cfg=cfg)[:b]
-    return _paged_logits_all(params, x, cfg=cfg)[:b]
+    dense = np.zeros((4, b_pad, s), np.int32)
+    dense[0, :b] = tokens
+    dense[1, :b] = positions
+    # Rows past the b*s live ones (the pad rows) are not written.
+    dense[2:] = np.reshape(
+        plan_rows(plans, b_pad * s, cache.num_pages), (2, b_pad, s)
+    )
+    host = {"dense": dense, "bt": bt, "kv_len": kv_len,
+            "sched": ops.schedule_arrays(schedule, rows=b_pad)}
+    _, p_last, x, attn = _paged_pass(
+        params, layers_p, cache, host, schedule, cfg=cfg, block_k=block_k,
+        variant=variant, interpret=interpret, compute_dtype=compute_dtype,
+        head_shards=head_shards,
+    )
+    logits = _paged_head(params, p_last, x, attn, None, cfg=cfg)
+    count("walk_dispatches")
+    return logits

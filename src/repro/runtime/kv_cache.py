@@ -112,32 +112,6 @@ def _write_rows_layered(pages, rows, pid, off):
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _write_rows_one_layer(pages, rows, layer, pid, off):
-    """Write ``rows (n, W)`` into page ``pid`` of a single layer."""
-    return jax.lax.dynamic_update_slice(
-        pages, rows[None, None].astype(pages.dtype), (layer, pid, off, 0)
-    )
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _write_token_rows_one_layer(pages, rows, layer, pids, offs):
-    """Scatter one row per request into a single layer's pages.
-
-    The decode-step fast path: every live request appends exactly one
-    latent row per layer, so the per-layer write is one fori_loop of B
-    in-place slice updates instead of B separate dispatches.
-    """
-
-    def body(i, p):
-        return jax.lax.dynamic_update_slice(
-            p, rows[i][None, None, None].astype(p.dtype),
-            (layer, pids[i], offs[i], 0),
-        )
-
-    return jax.lax.fori_loop(0, pids.shape[0], body, pages)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
 def _copy_page_layered(pages, dst_pid, src_pid):
     """Copy one physical page across **all** layers (layered COW fault)."""
     page = jax.lax.dynamic_slice(
@@ -156,37 +130,6 @@ def _write_rows_layered_quant(pages, scales, rows, pid, off):
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
-def _write_rows_one_layer_quant(pages, scales, rows, layer, pid, off):
-    """Quantized single-layer chunk write: ``rows (n, W)``."""
-    q, s = _quantize_rows(rows)
-    pages = jax.lax.dynamic_update_slice(
-        pages, q[None, None], (layer, pid, off, 0)
-    )
-    scales = jax.lax.dynamic_update_slice(
-        scales, s[None, None], (layer, pid, off)
-    )
-    return pages, scales
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _write_token_rows_one_layer_quant(pages, scales, rows, layer, pids, offs):
-    """Quantized decode-step scatter: one row per request, one layer."""
-    q, s = _quantize_rows(rows)  # (B, W) int8, (B,) f32
-
-    def body(i, c):
-        p, sc = c
-        p = jax.lax.dynamic_update_slice(
-            p, q[i][None, None, None], (layer, pids[i], offs[i], 0)
-        )
-        sc = jax.lax.dynamic_update_slice(
-            sc, s[i][None, None, None], (layer, pids[i], offs[i])
-        )
-        return p, sc
-
-    return jax.lax.fori_loop(0, pids.shape[0], body, (pages, scales))
-
-
-@functools.partial(jax.jit, donate_argnums=(0, 1))
 def _copy_page_layered_quant(pages, scales, dst_pid, src_pid):
     """Layered COW fault, quantized: every layer's page plane *and* scale
     row copy in the same call — one device op covers all L layers."""
@@ -199,6 +142,43 @@ def _copy_page_layered_quant(pages, scales, dst_pid, src_pid):
     )
     scales = jax.lax.dynamic_update_slice(scales, srow, (0, dst_pid, 0))
     return pages, scales
+
+
+def scatter_layer_rows(pages, scales, rows, layer, pids, offs):
+    """Write ``rows (R, W)`` into one layer of a layered pool: row ``i``
+    lands at ``(layer, pids[i], offs[i])``, in its first ``W`` lanes.
+
+    A row whose page id is out of range (``num_pages``, see
+    :func:`plan_rows`) is dropped.  Quantized pools (``scales`` not None)
+    take fp32-convertible rows and store int8 rows and their scales.  A
+    traceable function: the cache's own write and the model's per-layer
+    program both run it, on a donated pool.  Returns ``(pages, scales)``.
+    """
+    w = rows.shape[-1]
+    if scales is None:
+        rows = rows.astype(pages.dtype)
+        return pages.at[layer, pids, offs, :w].set(rows, mode="drop"), None
+    q, s = _quantize_rows(rows)
+    pages = pages.at[layer, pids, offs, :w].set(q, mode="drop")
+    return pages, scales.at[layer, pids, offs].set(s, mode="drop")
+
+
+_write_layer_rows = jax.jit(scatter_layer_rows, donate_argnums=(0, 1))
+
+
+def plan_rows(plans, rows: int, num_pages: int) -> tuple[np.ndarray, np.ndarray]:
+    """Per-row ``(page ids, offsets)`` of :meth:`PagedKVCache.reserve`
+    plans, taken in order, padded to ``rows`` with page id ``num_pages``
+    (out of range: :func:`scatter_layer_rows` drops those rows)."""
+    pids = np.full((rows,), num_pages, np.int32)
+    offs = np.zeros((rows,), np.int32)
+    w = 0
+    for plan in plans:
+        for pid, off0, m in plan:
+            pids[w : w + m] = pid
+            offs[w : w + m] = off0 + np.arange(m, dtype=np.int32)
+            w += m
+    return pids, offs
 
 
 class OutOfPagesError(RuntimeError):
@@ -749,10 +729,11 @@ class LayeredPagedKVCache(PagedKVCache):
 
     The data path is two-phase so appends compose with the sequential layer
     walk of a transformer: :meth:`reserve` claims the rows up front (once
-    per step), then each layer fills its own plane via :meth:`write_layer`
-    (chunked prefill) or :meth:`write_layer_tokens` (batched one-row decode
-    appends).  The inherited one-shot :meth:`append` takes ``(L, n, width)``
-    rows for callers that have all layers' latents in hand.
+    per pass), then each layer fills its own plane with
+    :func:`scatter_layer_rows` — inside the model's per-layer program, or
+    through :meth:`write_layer` / :meth:`write_layer_tokens`.  The
+    inherited one-shot :meth:`append` takes ``(L, n, width)`` rows for
+    callers that have all layers' latents in hand.
     """
 
     def __init__(
@@ -769,6 +750,7 @@ class LayeredPagedKVCache(PagedKVCache):
         if num_layers < 1:
             raise ValueError("need at least one layer")
         self.num_layers = num_layers
+        self._layer_ids = None
         super().__init__(
             num_pages=num_pages,
             page_size=page_size,
@@ -840,62 +822,48 @@ class LayeredPagedKVCache(PagedKVCache):
     def write_layer(
         self, layer: int, chunks: list[tuple[int, int, int]], rows: jax.Array
     ) -> None:
-        """Fill one layer's plane of reserved chunks with ``rows (n, W)``.
-
-        The chunked-prefill write: :meth:`reserve` once per chunk of
-        tokens, then every layer writes its latents into the same chunks.
-        """
-        rows = jnp.asarray(rows, self._row_dtype)
-        off = 0
-        for pid, in_page, m in chunks:
-            if self.quantized:
-                self.pages, self.scales = _write_rows_one_layer_quant(
-                    self.pages,
-                    self.scales,
-                    rows[off : off + m],
-                    jnp.int32(layer),
-                    jnp.int32(pid),
-                    jnp.int32(in_page),
-                )
-            else:
-                self.pages = _write_rows_one_layer(
-                    self.pages,
-                    rows[off : off + m],
-                    jnp.int32(layer),
-                    jnp.int32(pid),
-                    jnp.int32(in_page),
-                )
-            off += m
+        """Fill one layer's plane of reserved chunks with ``rows (n, W)``:
+        :meth:`reserve` once, then every layer writes into the same
+        chunks."""
+        n = sum(m for _, _, m in chunks)
+        self.write_layer_tokens(
+            layer, *plan_rows([chunks], n, self.num_pages), rows
+        )
 
     def write_layer_tokens(self, layer: int, pids, offs, rows) -> None:
         """Scatter ``rows (R, W)`` into one layer at ``(layer, pids[i],
-        offs[i])`` — the decode-step append, batched into a single donated
-        device call per layer.  R is one row per request for a plain step,
-        or ``B * draft_k`` rows for a speculative verify step (each row
-        carries its own page/offset, so a per-request run may cross a page
-        boundary mid-scatter).  Rows past ``len(pids)`` (the decode step's
-        batch padding) are not written.
-        """
-        rows = jnp.asarray(rows, self._row_dtype)
-        pids = jnp.asarray(pids, jnp.int32)
-        offs = jnp.asarray(offs, jnp.int32)
-        if self.quantized:
-            self.pages, self.scales = _write_token_rows_one_layer_quant(
-                self.pages, self.scales, rows, jnp.int32(layer), pids, offs
-            )
-        else:
-            self.pages = _write_token_rows_one_layer(
-                self.pages, rows, jnp.int32(layer), pids, offs
-            )
+        offs[i])`` in one donated device call (each row carries its own
+        page and offset, so a run of rows may cross a page boundary).
+        Rows past ``len(pids)`` are not written.  The model's layer walk
+        runs the same :func:`scatter_layer_rows` inside its own per-layer
+        program."""
+        pids = np.asarray(pids, np.int32)
+        rows = jnp.asarray(rows, self._row_dtype)[: pids.shape[0]]
+        self.pages, self.scales = _write_layer_rows(
+            self.pages, self.scales, rows, jnp.int32(layer), pids,
+            np.asarray(offs, np.int32),
+        )
 
-    def layer_pages(self, layer: int) -> jax.Array:
-        """The :func:`pool_shape` pool of one layer."""
-        return self.pages[layer]
+    @property
+    def device(self):
+        """The pool's device when it lives on one (else None, the default
+        device): where a pass's inputs are uploaded."""
+        devices = self.pages.devices()
+        return next(iter(devices)) if len(devices) == 1 else None
 
-    def layer_scales(self, layer: int) -> jax.Array | None:
-        """One layer's :func:`scale_pool_shape` scale pool (None unless
-        quantized) — passed to the kernels alongside :meth:`layer_pages`."""
-        return None if self.scales is None else self.scales[layer]
+    def layer_ids(self) -> tuple[jax.Array, ...]:
+        """One int32 scalar per layer on the pool's device, made once and
+        reused by every pass: the kernels and the per-layer write address
+        layer ``l`` of the stacked pool through ``layer_ids()[l]``, with no
+        upload per layer and no per-layer copy of the pool."""
+        device = self.device
+        if self._layer_ids is None or self._layer_ids[0].devices() != (
+            self.pages.devices()
+        ):
+            self._layer_ids = jax.device_put(
+                tuple(np.int32(l) for l in range(self.num_layers)), device
+            )
+        return self._layer_ids
 
     def gather_contiguous(self, rid: int, layer: int | None = None) -> jax.Array:
         """Contiguous ``(len, width)`` rows of one layer (or ``(L, len,

@@ -32,6 +32,16 @@ SERVE_SPANS = (
     "serve.admit",  # add_request
     "serve.finish",  # finish
 )
+# Counters kept beside the spans: the device programs the model's layer
+# walk launched (``models.transformer._paged_pass``).
+SERVE_COUNTERS = ("walk_dispatches",)
+
+
+def _first_token(logits) -> int:
+    """The greedy token of a one-request paged prefill's ``(1, vocab)``
+    logits: one argmax program and its read, nothing sliced on the
+    device."""
+    return int(np.asarray(jnp.argmax(logits, axis=-1))[0])
 
 
 def jit_serve_fns(
@@ -525,9 +535,10 @@ class PagedServingSession:
         self.prefill_chunks = 0
         self.prefill_stall_steps = 0
         # Wall-clock spans of add_request / step / finish and what runs
-        # inside them (SERVE_SPANS), and the wait of budgeted prompts from
-        # their enqueue to the start of their first prefill slice.
-        self.tracer = Tracer(SERVE_SPANS)
+        # inside them (SERVE_SPANS), the layer walk's program launches
+        # (SERVE_COUNTERS), and the wait of budgeted prompts from their
+        # enqueue to the start of their first prefill slice.
+        self.tracer = Tracer(SERVE_SPANS, SERVE_COUNTERS)
         self.prefill_wait_s = 0.0
         self.prefill_waits = 0
         # Recoverable eviction (suspend/resume by replay) + chaos state.
@@ -620,7 +631,7 @@ class PagedServingSession:
             "replay_prefill_tokens": self.replay_prefill_tokens,
             "replay_mismatches": self.replay_mismatches,
             # Interleaving proxies (virtual work clock), then wall clock:
-            # the prefill queue's wait and the span totals.
+            # the prefill queue's wait, the span totals and walk_dispatches.
             "work_units": self.work_units,
             "prefill_chunks": self.prefill_chunks,
             "prefill_stall_steps": self.prefill_stall_steps,
@@ -758,7 +769,7 @@ class PagedServingSession:
             # alias this request's pages without waiting for it to finish.
             self._retain_prompt(rid)
         with span("serve.sync", site="first_token"):
-            first = int(jnp.argmax(logits[0]))
+            first = _first_token(logits)
         return self._admit(rid, first)
 
     def _retain_prompt(self, rid: int) -> None:
@@ -863,7 +874,7 @@ class PagedServingSession:
             head_shards=self.head_shards,
         )
         self._count_prefill(len(suffix))
-        return self._admit(child, int(jnp.argmax(logits[0])))
+        return self._admit(child, _first_token(logits))
 
     # -- decode --------------------------------------------------------- #
     def step(self) -> None:
@@ -1057,7 +1068,7 @@ class PagedServingSession:
                 )
                 if final:
                     with span("serve.sync", site="first_token"):
-                        first = int(jnp.argmax(logits[0]))
+                        first = _first_token(logits)
             self._count_prefill(take, interleaved=True)
             ent["done"] += take
             if final:
@@ -1238,7 +1249,7 @@ class PagedServingSession:
             self.replay_prefill_tokens += len(prompt)
             if self.trie is not None:
                 self._retain_prompt(rid)
-            self._admit(rid, int(jnp.argmax(logits[0])))
+            self._admit(rid, _first_token(logits))
             self.resumes += 1
             return True
         tokens = rec.tokens
@@ -1280,7 +1291,7 @@ class PagedServingSession:
             # emitted from these same rows.  Counted rather than raised —
             # the stream stays serviceable — and gated at 0 by the
             # fault-tolerance tests and the failure_recovery benchmark.
-            if int(jnp.argmax(logits[0])) != int(rec.outputs[-1]):
+            if _first_token(logits) != int(rec.outputs[-1]):
                 self.replay_mismatches += 1
             self.replay_prefill_tokens += len(suffix)
             self._count_prefill(len(suffix))
@@ -1839,6 +1850,7 @@ class ShardedPagedServingSession:
                 "prefill_backlog_tokens",
                 "prefill_wait_s",
                 "prefill_waits",
+                *SERVE_COUNTERS,
             )
         }
         # Span totals (``span_n.*``, ``span_s.*``, ``span_self_s.*``) sum

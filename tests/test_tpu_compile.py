@@ -172,6 +172,80 @@ def test_prefill_rows_fit_vmem(one_chip):
     )
 
 
+# The v2-agent-8k cell's pool: 4 layers of 7168 pages of 32 rows.
+CELL_LAYERS, CELL_PAGES = 4, 7168
+
+
+@pytest.mark.parametrize(
+    "batch,rows", [(16, HEADS), (1, 32 * HEADS)], ids=["decode-16", "prefill-chunk"]
+)
+def test_stacked_pool_queue_kernel_compiles(one_chip, batch, rows):
+    """The queue kernel on one layer of the cell's stacked pool, addressed
+    in place (no layer is copied out): a decode step of 16 requests and a
+    32-token prefill chunk at 128 heads."""
+    kv_lens = [8192 + 131 * i for i in range(batch)]
+    schedule = sched.build_schedule(
+        np.asarray(kv_lens, np.int32), block_k=BLOCK_K, num_splits=1
+    )
+    s = one_chip
+
+    def kernel(q, pages, bt, kv_len, q_pos, layer, *queue):
+        return paged.mla_decode_paged_queue_rows(
+            q, pages, bt, kv_len, q_pos, *queue, None, layer, d_v=DV,
+            scale=SCALE, block_k=BLOCK_K,
+            num_dest_slots=schedule.num_dest_slots,
+        )
+
+    compiled = _compile(
+        kernel,
+        _spec(s, (batch, rows, lane_pad(DK)), jnp.bfloat16),
+        _spec(
+            s, (CELL_LAYERS,) + pool_shape(CELL_PAGES, PAGE, DK), jnp.bfloat16
+        ),
+        _spec(s, (batch, CELL_PAGES), jnp.int32),
+        _spec(s, (batch,), jnp.int32),
+        _spec(s, (batch, rows), jnp.int32),
+        _spec(s, (), jnp.int32),
+        *[_spec(s, (schedule.queue_len,), jnp.int32)] * 6,
+    )
+    one_layer = "[%d,%d,%d]" % pool_shape(CELL_PAGES, PAGE, DK)
+    assert one_layer not in compiled.as_text()
+
+
+@pytest.mark.parametrize("batch,s", [(16, 1), (1, 32)], ids=["decode", "prefill"])
+def test_fused_layer_program_compiles(one_chip, batch, s):
+    """The walk's per-layer program (the previous layer's post-attention
+    half, this layer's latents and queries, the write into the donated
+    pool, the kernel's query rows) at deepseek-v2 widths."""
+    from repro.models import transformer as tf
+
+    cfg = get_config("deepseek-v2-mla")
+    sh = one_chip
+    p_like = jax.eval_shape(
+        lambda: tf.layer_init(jax.random.PRNGKey(0), cfg, "global")
+    )
+    params = jax.tree.map(lambda a: _spec(sh, a.shape, a.dtype), p_like)
+    compiled = (
+        tf._paged_layer_in.lower(
+            None, params, params,
+            _spec(sh, (batch, s, cfg.d_model), jnp.bfloat16),
+            (_spec(sh, (batch, s * HEADS, DV), jnp.float32),),
+            _spec(sh, (4, batch, s), jnp.int32),
+            _spec(
+                sh, (CELL_LAYERS,) + pool_shape(CELL_PAGES, PAGE, DK),
+                jnp.bfloat16,
+            ),
+            None,
+            _spec(sh, (), jnp.int32),
+            cfg=cfg, compute_dtype=None, head_shards=1,
+        )
+        .compile()
+    )
+    text = compiled.as_text()
+    assert "input_output_alias" in text
+    assert "[%d,%d,%d]" % pool_shape(CELL_PAGES, PAGE, DK) not in text
+
+
 def test_prefill_rows_past_vmem_rejected():
     """A 64-token chunk at 128 heads (8192 rows) would need more VMEM than
     a core has: the queue kernel says so while tracing, before any compile."""
